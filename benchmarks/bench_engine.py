@@ -252,7 +252,7 @@ def _bench_setup(smoke: bool, seed: int):
     cfg = gnn_cfg(graph, model="graphsage", n_layers=2, fanout=(5, 3),
                   batch=64, hidden=32)
     kcfg = dataclasses.replace(cfg, model="gcn", use_agg_kernel=True,
-                               agg_interpret=True, agg_b_tile=8,
+                               agg_b_tile=8,
                                agg_d_tile=128, agg_k_slab=4)
     return graph, cfg, kcfg, iters, kernel_iters
 

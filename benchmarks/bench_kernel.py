@@ -108,8 +108,8 @@ def run(quick: bool = True, seed: int = 0):
         t_ref = (time.perf_counter() - t0) / 3
         for kernel in ("row", "tiled"):
             ker = neighbor_agg(feats, idx, w, use_kernel=True,
-                               kernel=kernel, interpret=True,
-                               b_tile=B_TILE, d_tile=D_TILE, k_slab=K_SLAB)
+                               kernel=kernel, b_tile=B_TILE, d_tile=D_TILE,
+                               k_slab=K_SLAB)
             err = float(jnp.max(jnp.abs(ref - ker)))
             flops = 2.0 * b * k * d
             acct = _accounting(kernel, n, d, b, k)

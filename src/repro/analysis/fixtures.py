@@ -24,8 +24,8 @@ from jax.experimental.pallas import tpu as pltpu
 # pallas: unmatched DMA wait
 # ---------------------------------------------------------------------------
 
-def make_unmatched_wait_kernel(b_tile: int, d_tile: int, k_slab: int,
-                               k_total: int, fuse_self: bool):
+def make_unmatched_wait_kernel(b_tile: int, n_tiles: int, k_slab: int,
+                               packed: bool, fuse_self: bool):
     """Same two-slot K-slab rotation as the real ``_make_tiled_kernel``
     but the wait is fenced to ``ki + 1 < nk``: the LAST slab's copies
     are consumed un-waited and leak past the output-tile boundary.
@@ -37,8 +37,7 @@ def make_unmatched_wait_kernel(b_tile: int, d_tile: int, k_slab: int,
                 sems = refs
         else:
             feat_ref, out_ref, rows_ref, acc_ref, sems = refs
-        bi = pl.program_id(0)
-        di = pl.program_id(1)
+        ti = pl.program_id(1)
         ki = pl.program_id(2)
         nk = pl.num_programs(2)
 
@@ -46,11 +45,10 @@ def make_unmatched_wait_kernel(b_tile: int, d_tile: int, k_slab: int,
             copies = []
             for j in range(k_slab):
                 for i in range(b_tile):
-                    nid = idx_ref[(bi * b_tile + i) * k_total
-                                  + slab * k_slab + j]
+                    nid = idx_ref[i, slab * k_slab + j]
                     copies.append(pltpu.make_async_copy(
-                        feat_ref.at[nid, pl.ds(di * d_tile, d_tile)],
-                        rows_ref.at[slot, j, i, :],
+                        feat_ref.at[nid * n_tiles + ti],
+                        rows_ref.at[slot, j, i],
                         sems.at[slot, j, i]))
             return copies
 
@@ -72,15 +70,15 @@ def make_unmatched_wait_kernel(b_tile: int, d_tile: int, k_slab: int,
             for c in slab_copies(ki, ki % 2):
                 c.wait()
 
-        w_blk = w_ref[...].astype(jnp.float32)
+        w_blk = w_ref[...]
         slot = ki % 2
         for j in range(k_slab):
-            acc_ref[...] += w_blk[:, j:j + 1] \
+            acc_ref[0] += w_blk[:, j:j + 1] \
                 * rows_ref[slot, j].astype(jnp.float32)
 
         @pl.when(ki == nk - 1)
         def _flush():
-            out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+            out_ref[...] = acc_ref[0].astype(out_ref.dtype)
 
     return kernel
 
@@ -107,7 +105,7 @@ def make_constant_capture_fn():
 
 def make_f64_fn():
     """-> (fn, example_arg): widens to float64.  Trace under
-    ``jax.experimental.enable_x64(True)`` so the widening survives into
+    ``jax.enable_x64(True)`` so the widening survives into
     the jaxpr instead of being silently clamped to f32."""
 
     def f(x):
@@ -165,9 +163,8 @@ def run_fixture(name: str):
         return _walk_hazards(jax.make_jaxpr(fn)(arg), "fixture:constant")
     if name == "f64":
         import jax
-        import jax.experimental
         fn, arg = make_f64_fn()
-        with jax.experimental.enable_x64(True):
+        with jax.enable_x64(True):
             closed = jax.make_jaxpr(fn)(arg)
         return _walk_hazards(closed, "fixture:f64")
     if name == "thread":

@@ -7,8 +7,9 @@ and ``bind``-ed exactly like ``experiment.make_source`` does (minus
 worker threads: sampled sources run with ``prefetch=False,
 reuse_buffers=False`` and the cluster batch is drawn through
 ``_sample_union`` directly), and the step comes out of
-``engine._cached_step`` with the source's own ``loss_consts()``, so the
-audited jaxpr IS the jaxpr a sweep compiles, not a lookalike.
+``engine._cached_step`` with the source's own ``loss_consts()`` (their
+arrays as step arguments), so the audited jaxpr IS the jaxpr a sweep
+compiles, not a lookalike.
 
 Hazard classes (ISSUE 9):
 
@@ -19,13 +20,12 @@ Hazard classes (ISSUE 9):
   output of another ``convert_element_type``: a round-trip (A->B->A)
   is a wasted pass over the array (warning); other double-converts
   collapse to one and are reported as info.
-* **host-constant capture** — ``np.ndarray`` constants above a size
-  threshold folded into the jaxpr.  Host arrays bake into the HLO as
-  literals AND miss every identity-keyed trace cache, so a captured
-  feature table is simultaneously an HBM and a retrace hazard.
-  (Device ``jax.Array`` consts are the engine's deliberate design —
-  ``_cached_step`` closes over the memoized ELL upload — and are
-  tallied in the per-variant record, not flagged.)
+* **constant capture** — host ``np.ndarray`` or device ``jax.Array``
+  constants above a size threshold folded into the jaxpr.  Either bakes
+  into the executable: a captured ELL or feature table makes a program
+  of the table's size (2.4 GB at papers100M scale) that compiles slowly
+  and that no compile cache keeps.  The engine passes its arrays as
+  step arguments (``engine._split_consts``).
 * **collectives outside shard_map** — psum/all_gather/... equations
   not nested under a ``shard_map`` body run under GSPMD semantics
   where they are almost always a tracing bug in this codebase.
@@ -34,8 +34,8 @@ Hazard classes (ISSUE 9):
   buffer reuse (error); donated batch leaves are donated for early
   deallocation only and are tallied, not flagged.
 * **retrace stability** — a fresh source instance bound to the same
-  graph must (a) hit ``_cached_step``'s identity-keyed cache (same
-  function object back) and (b) retrace to a byte-identical canonical
+  graph must (a) hit ``_cached_step``'s cache (same function object
+  back) and (b) retrace to a byte-identical canonical
   jaxpr.  Either failing means a ``sweep()`` recompiles per grid
   point and every bench number downstream is measuring the compiler.
 """
@@ -58,8 +58,7 @@ COLLECTIVES = frozenset({
 #: primitives that introduce a shard_map scope for everything below
 _SPMD_SCOPES = frozenset({"shard_map"})
 
-#: host (np.ndarray) constants this large baked into a jaxpr are an
-#: HLO-literal + retrace hazard; device consts are the engine's design
+#: constants this large baked into a jaxpr are an executable-size hazard
 HOST_CONST_BYTES = 4096
 
 F64 = frozenset({"float64", "complex128"})
@@ -112,7 +111,7 @@ def variant_cfg(graph, v: Variant):
         feat_dim=graph.feats.shape[1], hidden=16,
         n_classes=graph.n_classes, n_layers=2, fanout=(4, 3),
         batch_size=32, loss="ce", use_agg_kernel=v.kernel,
-        agg_interpret=True, agg_b_tile=8, agg_d_tile=16, agg_k_slab=2,
+        agg_b_tile=8, agg_d_tile=16, agg_k_slab=2,
         feats_layout="sharded" if v.featshard else "replicated")
 
 
@@ -159,7 +158,7 @@ def _draw_batch(src, graph):
 
 def _subjaxprs(params: Dict) -> Iterable[Tuple[Any, bool]]:
     """-> (sub-closed/open jaxpr, introduces_shard_map_scope)."""
-    import jax.core as jcore
+    import jax.extend.core as jcore
     for val in params.values():
         stack = [val]
         while stack:
@@ -172,7 +171,7 @@ def _subjaxprs(params: Dict) -> Iterable[Tuple[Any, bool]]:
 
 def _iter_eqns(jaxpr, in_spmd: bool = False):
     """Depth-first (eqn, inside_shard_map) over a (Closed)Jaxpr."""
-    import jax.core as jcore
+    import jax.extend.core as jcore
     if isinstance(jaxpr, jcore.ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     for eqn in jaxpr.eqns:
@@ -184,7 +183,8 @@ def _iter_eqns(jaxpr, in_spmd: bool = False):
 
 def _walk_hazards(closed, site: str) -> List[Finding]:
     """The per-jaxpr hazard walks shared by step/eval/inference."""
-    import jax.core as jcore
+    import jax
+    import jax.extend.core as jcore
     out: List[Finding] = []
 
     f64_counts: Dict[str, int] = {}
@@ -244,19 +244,19 @@ def _walk_hazards(closed, site: str) -> List[Finding]:
             "all-reduce bug, not a partitioning hint"))
 
     # -- constants folded into the jaxpr --------------------------------
-    host_bytes = dev_bytes = 0
     for c in getattr(closed, "consts", ()):
-        if isinstance(c, np.ndarray):
-            host_bytes += c.nbytes
-            if c.nbytes >= HOST_CONST_BYTES:
-                out.append(Finding(
-                    "jaxpr", "error", site,
-                    f"host np.ndarray constant {c.shape} {c.dtype} "
-                    f"({c.nbytes} B) folded into the jaxpr — bakes an "
-                    "HLO literal and defeats every identity-keyed "
-                    "trace cache (closure-captured table?)"))
-        elif hasattr(c, "nbytes"):       # jax.Array: deliberate consts
-            dev_bytes += int(c.nbytes)
+        kind = "device jax.Array"
+        if not isinstance(c, jax.Array):
+            # a host array (jax wraps captured numpy tables in its own
+            # typed-literal class; np.asarray unwraps either form)
+            c, kind = np.asarray(c), "host np.ndarray"
+        nbytes = int(getattr(c, "nbytes", 0))
+        if nbytes >= HOST_CONST_BYTES:
+            out.append(Finding(
+                "jaxpr", "error", site,
+                f"{kind} constant {tuple(c.shape)} {c.dtype} ({nbytes} B) "
+                "folded into the jaxpr — it bakes into the executable "
+                "(closure-captured table? pass it as an argument)"))
     return out
 
 
@@ -264,10 +264,12 @@ def _canonical_hash(closed) -> str:
     return hashlib.sha256(str(closed.jaxpr).encode()).hexdigest()[:16]
 
 
-def _donation_findings(closed, site: str, n_batch_leaves: int
-                       ) -> Tuple[List[Finding], Dict]:
+def _donation_findings(closed, site: str, n_batch_leaves: int,
+                       n_trailing: int = 0) -> Tuple[List[Finding], Dict]:
     """Check that donated params/opt leaves can actually alias an
-    output buffer; donated batch leaves are early-free only (tallied)."""
+    output buffer; donated batch leaves are early-free only (tallied).
+    ``n_trailing`` undonated inputs (the step's const arrays) follow
+    the batch leaves."""
     out: List[Finding] = []
     eqns = closed.jaxpr.eqns
     rec = {"donated": 0, "donated_unaliasable_batch": 0}
@@ -290,7 +292,8 @@ def _donation_findings(closed, site: str, n_batch_leaves: int
         rec["donated"] += 1
         a = v.aval
         k = (a.shape, str(a.dtype))
-        is_batch = n_batch_leaves and i >= n_in - n_batch_leaves
+        is_batch = (n_batch_leaves
+                    and i >= n_in - n_trailing - n_batch_leaves)
         if pool.get(k, 0) > 0:
             pool[k] -= 1
         elif is_batch:
@@ -328,23 +331,24 @@ def audit_variant(graph, v: Variant, plan=None
         src = _make_source(v, cfg).bind(graph, cfg, plan)
         try:
             consts = src.loss_consts()
-            step = E._cached_step(graph, type(src), consts, cfg, plan)
+            step, arrays = E._cached_step(graph, type(src), consts, cfg,
+                                          plan)
             params = src.place(
                 G.init_gnn(jax.random.key(0), cfg,
                            graph.feats.shape[1]))
             opt_state = src.place(plan.make_optimizer().init(params))
             batch = _draw_batch(src, graph)
-            closed = jax.make_jaxpr(step)(params, opt_state, batch)
+            closed = jax.make_jaxpr(step)(params, opt_state, batch, arrays)
             n_batch = len(jax.tree.leaves(batch))
-            return step, closed, n_batch
+            return step, closed, n_batch, len(arrays)
         finally:
             src.close()
 
-    step1, closed1, n_batch = trace_once()
-    step2, closed2, _ = trace_once()
+    step1, closed1, n_batch, n_arrays = trace_once()
+    step2, closed2, _, _ = trace_once()
 
     findings += _walk_hazards(closed1, site)
-    don, drec = _donation_findings(closed1, site, n_batch)
+    don, drec = _donation_findings(closed1, site, n_batch, n_arrays)
     findings += don
     rec.update(drec)
 
@@ -356,9 +360,8 @@ def audit_variant(graph, v: Variant, plan=None
         findings.append(Finding(
             "jaxpr", "error", site,
             "_cached_step returned a DIFFERENT function for a fresh "
-            "source bound to the same graph — the consts-identity "
-            "cache key is unstable and every sweep grid point "
-            "recompiles"))
+            "source bound to the same graph — the step cache key is "
+            "unstable and every sweep grid point recompiles"))
     if h1 != h2:
         findings.append(Finding(
             "jaxpr", "error", site,
@@ -385,7 +388,7 @@ def _audit_eval(graph, v: Variant) -> Tuple[List[Finding], Dict]:
         mesh = getattr(src, "_mesh", None)
         fsplan = getattr(src, "feats_plan", None)
         closed = jax.make_jaxpr(
-            E._eval_acc, static_argnums=(1, 8, 9))(
+            E._eval_acc, static_argnums=(1, 8))(
                 params, E._static_cfg(cfg), idx, w, w_self, feats,
                 labels, src.node_split("val"), mesh, fsplan)
     finally:

@@ -24,7 +24,7 @@ Three checks over the repo's kernels (``neighbor_agg`` row + tiled,
    matches its start, and zero in-flight copies at every output-tile
    boundary (so any megacore partition of the parallel axes is safe).
 
-3. **Scalar-prefetch bounds** — every gather index that addresses an
+3. **Gather-id bounds** — every gather index that addresses an
    operand row must be in range; the simulator checks the ids the
    kernel actually dereferences, and ``check_index_bounds`` validates
    the real host-side index tables (ELL, featshard plan) an audit graph
@@ -52,24 +52,29 @@ WARN_FRACTION = 0.75
 # ---------------------------------------------------------------------------
 
 def tiled_agg_budget(b_tile: int, d_tile: int, k_slab: int, *,
-                     feat_itemsize: int = 4, out_itemsize: int = 4,
+                     packed: bool = False,
                      fuse_self: bool = False) -> Dict[str, int]:
     """Per-step VMEM bytes of ``neighbor_agg_pallas_tiled``
     (neighbor_agg.py ``_make_tiled_kernel``): the manually-DMA'd row
     double buffer + f32 accumulator scratch, plus the grid-blocked
     operands (w / optional fused-self blocks / out), each double-
-    buffered by the Pallas pipeline.  feats stays in HBM (ANY) — 0."""
+    buffered by the Pallas pipeline.  Rows, self rows and out are 32-bit
+    words (``packed`` bf16: two columns per word, two f32 accumulator
+    parts).  The weight block spans the whole K; it is counted here at
+    K = k_slab (it adds b_tile·K·8 B, 2 KiB at b_tile=8, K=32).  feats
+    stays in HBM (ANY) and the ids in SMEM — 0."""
+    n_parts = 2 if packed else 1
     parts = {
         "scratch rows[2,k_slab,b_tile,d_tile]":
-            2 * k_slab * b_tile * d_tile * feat_itemsize,
-        "scratch acc[b_tile,d_tile] f32": b_tile * d_tile * 4,
-        "block w[b_tile,k_slab] x2": 2 * b_tile * k_slab * 4,
-        "block out[b_tile,d_tile] x2": 2 * b_tile * d_tile * out_itemsize,
+            2 * k_slab * b_tile * d_tile * 4,
+        "scratch acc[parts,b_tile,d_tile] f32":
+            n_parts * b_tile * d_tile * 4,
+        "block w[b_tile,K] f32 x2": 2 * b_tile * k_slab * 4,
+        "block out[b_tile,d_tile] x2": 2 * b_tile * d_tile * 4,
     }
     if fuse_self:
         parts["block w_self[b_tile,1] x2"] = 2 * b_tile * 4
-        parts["block self[b_tile,d_tile] x2"] = \
-            2 * b_tile * d_tile * feat_itemsize
+        parts["block self[b_tile,d_tile] x2"] = 2 * b_tile * d_tile * 4
     return parts
 
 
@@ -114,13 +119,13 @@ def default_budget_table() -> List[Dict]:
     bf16 feature tables, with and without the fused self epilogue), the
     seed row kernel, and flash_attn at its default blocks."""
     rows = []
-    for item, tag in ((4, "f32"), (2, "bf16")):
+    for packed, tag in ((False, "f32"), (True, "bf16")):
         for fuse in (False, True):
             case = f"b8 d128 k4 {tag}" + (" +self" if fuse else "")
             rows.append(budget_row(
                 "neighbor_agg_tiled", case,
-                tiled_agg_budget(8, 128, 4, feat_itemsize=item,
-                                 out_itemsize=item, fuse_self=fuse)))
+                tiled_agg_budget(8, 128, 4, packed=packed,
+                                 fuse_self=fuse)))
     rows.append(budget_row("neighbor_agg_row", "d128 f32",
                            row_agg_budget(128)))
     rows.append(budget_row("flash_attn", "q128 k128 d128 f32",
@@ -263,13 +268,15 @@ class _Harness:
         self.bad_ids: List[Tuple[str, int]] = []
 
     def on_index(self, name: str, key: Tuple) -> None:
-        # the feature-table gather: first index is the scalar-prefetched
-        # neighbor id — must address a real row
+        # the feature-table gather: the first index is the word row
+        # nid * n_tiles + tile of the SMEM-staged neighbor id — it must
+        # address a real row
+        n_tiles = self.grid[1]
         if name == "feat" and key:
-            nid = key[0]
-            if isinstance(nid, (int, np.integer)) \
-                    and not 0 <= int(nid) < self.n_rows:
-                self.bad_ids.append((name, int(nid)))
+            row = key[0]
+            if isinstance(row, (int, np.integer)) \
+                    and not 0 <= int(row) < self.n_rows * n_tiles:
+                self.bad_ids.append((name, int(row) // n_tiles))
 
 
 def simulate_dma_pairing(make_kernel, *, b_tile: int = 2, d_tile: int = 8,
@@ -279,10 +286,13 @@ def simulate_dma_pairing(make_kernel, *, b_tile: int = 2, d_tile: int = 8,
                          grid_bd: Tuple[int, int] = (2, 2),
                          idx: Optional[np.ndarray] = None
                          ) -> List[Finding]:
-    """Execute ``make_kernel(b_tile, d_tile, k_slab, k_total,
-    fuse_self)``'s kernel over a ``(grid_bd[0], grid_bd[1], nk)`` grid
-    in row-major order (K innermost + sequential, matching the kernel's
-    ``dimension_semantics``) and verify DMA/semaphore discipline.
+    """Execute ``make_kernel(b_tile, n_tiles, k_slab, packed,
+    fuse_self)``'s kernel (f32 rows, n_tiles = ``grid_bd[1]``) over a
+    ``(grid_bd[0], grid_bd[1], nk)`` grid in row-major order (K
+    innermost + sequential, matching the kernel's
+    ``dimension_semantics``) and verify DMA/semaphore discipline.  The
+    SMEM id block handed to each grid row is that row's slice of
+    ``idx`` [B, K], as the Pallas pipeline stages it.
 
     The kernel's module-level ``pl`` / ``pltpu`` / ``jnp`` names are
     swapped for stubs via ``__globals__`` for the duration — local to
@@ -293,21 +303,22 @@ def simulate_dma_pairing(make_kernel, *, b_tile: int = 2, d_tile: int = 8,
     grid = (gb, gd, nk)
     site = f"{site}[fuse_self={fuse_self},nk={nk}]"
     h = _Harness(grid, n_rows)
-    kernel = make_kernel(b_tile, d_tile, k_slab, k_total, fuse_self)
+    kernel = make_kernel(b_tile, gd, k_slab, False, fuse_self)
 
     rng = np.random.default_rng(0)
     if idx is None:
         idx = rng.integers(0, n_rows, size=b * k_total).astype(np.int32)
+    idx = np.asarray(idx).reshape(-1)[:b * k_total].reshape(b, k_total)
     refs = dict(
-        idx=_Ref("idx", np.asarray(idx).reshape(-1)),
-        w=_Ref("w", np.ones((b_tile, k_slab), np.float32)),
+        idx=_Ref("idx", idx[:b_tile]),
+        w=_Ref("w", np.ones((b_tile, k_total), np.float32)),
         wself=_Ref("wself", np.ones((b_tile, 1), np.float32)),
         self_=_Ref("self", np.ones((b_tile, d_tile), np.float32)),
         feat=_Ref("feat", harness=h),
         out=_Ref("out", np.zeros((b_tile, d_tile), np.float32)),
         rows=_Ref("rows", np.zeros((2, k_slab, b_tile, d_tile),
                                    np.float32)),
-        acc=_Ref("acc", np.zeros((b_tile, d_tile), np.float32)),
+        acc=_Ref("acc", np.zeros((1, b_tile, d_tile), np.float32)),
         sems=_Ref("sem", harness=h),
     )
     if fuse_self:
@@ -326,6 +337,7 @@ def simulate_dma_pairing(make_kernel, *, b_tile: int = 2, d_tile: int = 8,
     findings: List[Finding] = []
     try:
         for bi in range(gb):
+            refs["idx"].arr = idx[bi * b_tile:(bi + 1) * b_tile]
             for di in range(gd):
                 pane_start = len(h.events)
                 for ki in range(nk):
@@ -345,7 +357,7 @@ def simulate_dma_pairing(make_kernel, *, b_tile: int = 2, d_tile: int = 8,
     for name, nid in h.bad_ids[:4]:
         findings.append(Finding(
             "pallas", "error", site,
-            f"scalar-prefetched index {nid} addresses {name} rows "
+            f"gather id {nid} addresses {name} rows "
             f"outside [0, {n_rows})"))
     return findings
 

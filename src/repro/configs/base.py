@@ -133,7 +133,9 @@ class GNNConfig:
     n_layers: int = 2
     fanout: Tuple[int, ...] = (15, 10)   # β per hop (mini-batch)
     batch_size: int = 1024               # b (mini-batch)
-    max_degree: int = 32                 # ELL padding for full-graph
+    # ELL width (neighbours kept per row) of full-graph training,
+    # evaluation and the embedding store; None keeps every neighbour
+    max_degree: Optional[int] = None
     gat_heads: int = 4
     dtype: str = "float32"
     loss: str = "ce"                     # ce | mse
@@ -141,10 +143,11 @@ class GNNConfig:
     # Routes the Ã-weighted aggregation of gcn/graphsage through the
     # batch-tiled software-gather kernel in BOTH forward paths.  GAT keeps
     # the einsum path (per-edge softmax attention is not a weighted sum).
+    # The kernel compiles on a TPU backend and runs in the Pallas
+    # interpreter on any other (repro.kernels.default_interpret).
     use_agg_kernel: bool = False
-    agg_interpret: bool = True           # interpret mode on CPU; False on TPU
     agg_b_tile: int = 8
-    agg_d_tile: int = 128
+    agg_d_tile: int = 128                # 32-bit lanes per step; 128 on TPU
     agg_k_slab: int = 4
     # --- feature-table layout (kernels/neighbor_agg/featshard) ---
     # "replicated": every device holds the full [n, d] gather source (the
@@ -185,7 +188,7 @@ class GNNConfig:
             f"batch_size must not exceed the graph "
             f"(b={self.batch_size} > n_nodes={self.n_nodes}); the engine "
             f"pads b > n_train, but b > n can only be a grid typo")
-        req(self.max_degree > 0,
+        req(self.max_degree is None or self.max_degree > 0,
             f"max_degree must be > 0, got {self.max_degree}")
         if self.model == "gat":
             req(self.gat_heads > 0,

@@ -22,16 +22,14 @@ def full_config() -> GNNConfig:
         batch_size=8192,
         max_degree=32,
         dtype="bfloat16",   # aggregation traffic dtype (§Perf H1)
-        # Real-TPU fast path: the batch-tiled, double-buffered Pallas
-        # gather (compiled, not interpret mode) — mesh-ready since the
-        # shard_map partitioning over the NODES axis, so both sharded
-        # sources run it on N devices.  When hardware is around, record
-        # the HBM-bound step times into the BENCH_engine.json trajectory
-        # (`make bench-engine-baseline` on the TPU host) next to the
-        # CPU-interpret rows; the launch/dryrun.py CPU compile forces
-        # the einsum path instead (Mosaic won't lower off-TPU).
+        # The batch-tiled, double-buffered Pallas gather: compiled by
+        # Mosaic on a TPU (interpret mode only off-TPU), and mesh-ready
+        # through the shard_map partitioning over the NODES axis.
+        # chip_smoke.py trains and serves this config on a v5e;
+        # tests/test_tpu_compile.py compiles its steps for a described
+        # v5e.  The 512-device launch/dryrun.py runs on virtual CPU
+        # devices and takes the einsum path instead.
         use_agg_kernel=True,
-        agg_interpret=False,
         source="Liu et al. 2026 / ogbn-papers100M (Hu et al. 2020)",
     )
 

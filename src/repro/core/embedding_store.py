@@ -104,7 +104,8 @@ class TableSnapshot:
 class EmbeddingStore:
     """Per-layer embedding cache over a (mutable) graph.
 
-    ``max_deg=None`` keeps full neighborhoods (inference default);
+    ``max_deg=None`` takes the config's ``max_degree`` (None keeps full
+    neighborhoods, the inference default);
     ``mesh`` routes chunk aggregation through the NODES-sharded kernel
     path (requires ``cfg.use_agg_kernel``).
 
@@ -120,11 +121,11 @@ class EmbeddingStore:
         self.cfg = cfg
         self._scfg = _static_cfg(cfg)
         self.graph = graph
-        self.max_deg = max_deg
+        self.max_deg = cfg.max_degree if max_deg is None else max_deg
         self.mesh = mesh
         self.prefetch = prefetch
         self.chunk_size = max(1, min(int(chunk_size), graph.n))
-        self.idx, self.w, self.w_self = to_ell(graph, max_deg=max_deg)
+        self.idx, self.w, self.w_self = to_ell(graph, max_deg=self.max_deg)
         self.K = self.idx.shape[1]
         self._h0 = jnp.asarray(graph.feats)
         # feats_layout="sharded": the full build runs the NODES-sharded

@@ -41,9 +41,10 @@ Throughput path (docs/training_api.md "Throughput knobs"):
   to the synchronous read (their semantics need the loss on host
   immediately);
 - compiled steps are CACHED per graph across Trainer instances (keyed
-  by source type, normalized config, optimizer spec and the identity of
-  the device constants), so a ``sweep()`` grid point with the same
-  effective shapes never re-traces; partial batches are padded up to
+  by source type, normalized config, optimizer spec and the static part
+  of the source's constants; the constants' arrays are step arguments),
+  so a ``sweep()`` grid point with the same effective shapes never
+  re-traces; partial batches are padded up to
   the plan's batch size with masked-out rows so each grid point
   compiles exactly one step function;
 - evaluation and full-loss tracking run through module-level jitted
@@ -138,31 +139,47 @@ def _static_cfg(cfg: GNNConfig) -> GNNConfig:
         fanout=(1,) * cfg.n_layers, max_degree=1, n_nodes=0, feat_dim=0)
 
 
-@functools.partial(jax.jit, static_argnums=(1, 8, 9))
+@functools.partial(jax.jit, static_argnums=(1, 8))
 def _eval_acc(params, cfg: GNNConfig, idx, w, w_self, feats, labels,
               nodes, mesh=None, feats_plan=None):
-    # feats_plan (identity-hashed FeatShardPlan) rides as a STATIC arg:
-    # it only steers tracing (featshard vs replicated kernel dispatch);
-    # its device index arrays are closed over inside the op
+    # feats_plan (a FeatShardPlan pytree, or None) is an ordinary
+    # argument: its index arrays are leaves, its layout static aux data
     logits = G.full_graph_forward(params, cfg, feats, idx, w, w_self,
                                   mesh=mesh, feats_plan=feats_plan)
     return G.accuracy(logits[nodes], labels[nodes])
 
 
+def _split_consts(consts) -> Tuple[Tuple, Tuple, TCallable]:
+    """Split a source's ``loss_consts()`` into the arrays a compiled
+    function takes as ARGUMENTS and the static rest (meshes; a
+    featshard plan's layout is static aux data of its pytree).
+
+    -> ``(arrays, static_key, join)`` where ``join(arrays)`` rebuilds the
+    consts inside the traced function.  Arrays are never closed over:
+    a closed-over device array becomes a constant of the executable (at
+    papers100M scale a 2.4 GB program that no compile cache keeps), and
+    a cache keyed on its identity recompiles for every fresh upload.
+    """
+    leaves, tdef = jax.tree.flatten(consts)
+    is_arr = tuple(isinstance(x, (jax.Array, np.ndarray)) for x in leaves)
+    arrays = tuple(x for x, a in zip(leaves, is_arr) if a)
+    static = tuple(x for x, a in zip(leaves, is_arr) if not a)
+
+    def join(arrs):
+        it_a, it_s = iter(arrs), iter(static)
+        return jax.tree.unflatten(
+            tdef, [next(it_a) if a else next(it_s) for a in is_arr])
+
+    return arrays, (tdef, is_arr, static), join
+
+
 def _graph_fn_cache(graph: Graph, key, build):
     """Per-graph compiled-function cache (dies with the graph): sweeps
-    re-create Trainers per grid point, but grid points with the same
-    effective shapes reuse ONE compiled step / full-loss function.
-
-    ``key[-1]`` is the identity tuple of the device constants the
-    function closes over; the entry holds those constants so the ids
-    stay valid while it is alive.  Inserting an entry EVICTS entries
-    for the same logical function with different (stale) constants —
-    e.g. a sweep over distinct ``max_deg`` re-uploads the ELL per grid
-    point, and without eviction each cached closure would pin a full
-    upload on device (the accretion satellite #1 fixed in
-    ``_device_ell`` would just move here).  A FIFO bound caps the rest.
-    """
+    re-create Trainers per grid point, and grid points with the same
+    static configuration reuse ONE jitted step / full-loss function
+    (jit itself keys its executables on the argument shapes).  The
+    functions hold no device arrays, so an entry pins no upload; a
+    FIFO bound caps the count."""
     cache = getattr(graph, "_fn_cache", None)
     if cache is None:
         cache = {}
@@ -170,41 +187,38 @@ def _graph_fn_cache(graph: Graph, key, build):
     hit = cache.get(key)
     if hit is None:
         hit = build()
-        for stale in [k for k in cache if k[:-1] == key[:-1]]:
-            del cache[stale]
         while len(cache) >= 16:
             del cache[next(iter(cache))]
         cache[key] = hit
-    return hit[0]
+    return hit
 
 
 def _cached_full_loss(graph: Graph, cfg: GNNConfig, ell, sel, mesh=None,
                       feats_plan=None):
-    """Full-training-objective loss (params -> device scalar), closure
-    over the device ELL (closing over, instead of passing as arguments,
-    keeps the pre-cache jaxpr and therefore the golden full-loss values
-    bit-for-bit).  ``mesh`` (sharded sources with the kernel on)
+    """Full-training-objective loss (params -> device scalar) over the
+    device ELL.  ``mesh`` (sharded sources with the kernel on)
     partitions the kernel's aggregation over the NODES axis;
     ``feats_plan`` additionally row-shards the source table
-    (feats_layout="sharded")."""
+    (feats_layout="sharded").  The ELL, ``sel`` and the plan's arrays
+    are arguments of the jitted function (``_split_consts``)."""
     scfg = _static_cfg(cfg)
-    key = ("full_loss", scfg, mesh,
-           tuple(id(c) for c in ell) + (id(sel), id(feats_plan)))
+    arrays, static, join = _split_consts((tuple(ell), sel, mesh,
+                                          feats_plan))
 
     def build():
-        idx, w, w_self, feats, labels = ell
-
         @jax.jit
-        def full_loss(params):
+        def full_loss(params, arrs):
+            (idx, w, w_self, feats, labels), sel_, mesh_, plan_ = join(arrs)
             logits = G.full_graph_forward(params, scfg, feats, idx, w,
-                                          w_self, mesh=mesh,
-                                          feats_plan=feats_plan)
-            return G.gnn_loss(logits[sel], labels[sel], scfg.loss,
+                                          w_self, mesh=mesh_,
+                                          feats_plan=plan_)
+            return G.gnn_loss(logits[sel_], labels[sel_], scfg.loss,
                               scfg.n_classes)
 
-        return full_loss, (ell, sel, feats_plan)
+        return full_loss
 
-    return _graph_fn_cache(graph, key, build)
+    fn = _graph_fn_cache(graph, ("full_loss", scfg, static), build)
+    return lambda params: fn(params, arrays)
 
 
 def evaluate_full(params, cfg: GNNConfig, graph: Graph, ell, nodes,
@@ -363,34 +377,35 @@ def _cached_step(graph: Graph, src_cls: type, consts: Tuple,
                  cfg: GNNConfig, plan: TrainPlan):
     """Compiled train step, cached ON THE GRAPH across Trainer instances.
 
-    The step closes over ``consts`` (e.g. the ELL tuple — closing over
-    them keeps the pre-cache jaxprs, and therefore the golden loss
-    sequences, bit-for-bit) so the cache key is (source type, normalized
-    config, optimizer spec, donation flag, consts identity).  Because
-    ``_device_ell`` memoizes the device uploads per graph, every grid
-    point of a ``sweep()`` with the same effective shapes hits the same
-    compiled step instead of re-tracing.
+    -> ``(step, arrays)``: call ``step(params, opt_state, batch,
+    arrays)``.  ``arrays`` are the device arrays of ``consts`` (e.g. the
+    ELL tuple), passed as arguments rather than closed over
+    (``_split_consts``), so the cache key is (source type, normalized
+    config, optimizer spec, donation flag, the consts' static part) and
+    every grid point of a ``sweep()`` with the same static
+    configuration hits the same jitted step; jit compiles once per
+    argument shape.
     """
     scfg = _static_cfg(cfg)
+    arrays, static, join = _split_consts(consts)
     key = ("step", src_cls.__qualname__, scfg, _opt_key(plan),
-           plan.donate, tuple(id(c) for c in consts))
+           plan.donate, static)
 
     def build():
         opt = plan.make_optimizer()
 
-        def step(params, opt_state, batch):
+        def step(params, opt_state, batch, arrs):
             loss, grads = jax.value_and_grad(
-                lambda p: src_cls._loss_impl(p, batch, consts, scfg)
+                lambda p: src_cls._loss_impl(p, batch, join(arrs), scfg)
             )(params)
             params, opt_state, good = _guarded_update(
                 opt, params, opt_state, loss, grads)
             return params, opt_state, loss, good
 
-        fn = jax.jit(step,
-                     donate_argnums=(0, 1, 2) if plan.donate else ())
-        return fn, consts
+        return jax.jit(step,
+                       donate_argnums=(0, 1, 2) if plan.donate else ())
 
-    return _graph_fn_cache(graph, key, build)
+    return _graph_fn_cache(graph, key, build), arrays
 
 
 # ---------------------------------------------------------------------------
@@ -430,7 +445,8 @@ class BatchSource:
         raise NotImplementedError
 
     def loss_consts(self) -> Tuple:
-        """Device constants closed over by the cached step."""
+        """Constants of the cached step: arrays become step arguments,
+        anything else (a mesh) is static (``_split_consts``)."""
         return ()
 
     def node_split(self, which: str):
@@ -480,12 +496,17 @@ class FullGraphSource(BatchSource):
     name = "fullgraph"
 
     def __init__(self, max_deg: Optional[int] = None):
+        # ELL width cap; None takes the config's ``max_degree`` (itself
+        # None = every neighbour).  A (b, beta) sweep passes its beta.
         self.max_deg = max_deg
         self.ell = None
 
+    def _cap(self, cfg: GNNConfig) -> Optional[int]:
+        return cfg.max_degree if self.max_deg is None else self.max_deg
+
     def bind(self, graph, cfg, plan):
         self.graph, self.cfg = graph, cfg
-        self.ell = _device_ell(graph, self.max_deg)
+        self.ell = _device_ell(graph, self._cap(cfg))
         self.train_nodes = _device_nodes(graph, "train")
         self.n_nodes = len(graph.train_nodes)
         return self
@@ -547,17 +568,16 @@ class ShardedFullGraphSource(FullGraphSource):
         n_dev = int(np.prod(list(mesh.shape.values())))
         # memoized per graph like _device_ell (same one-resident-key
         # eviction): a sweep over sharded grid points reuses ONE upload
-        # and therefore ONE compiled step (the step cache keys on the
-        # consts' identity)
+        cap = self._cap(cfg)
         key = (tuple(d.id for d in mesh.devices.flat),
-               _resolve_max_deg(graph, self.max_deg))
+               _resolve_max_deg(graph, cap))
         cache = getattr(graph, "_sharded_ell_cache", None)
         if cache is None:
             cache = {}
             object.__setattr__(graph, "_sharded_ell_cache", cache)
         if key not in cache:
             cache.clear()
-            idx, w, w_self = to_ell(graph, max_deg=self.max_deg)
+            idx, w, w_self = to_ell(graph, max_deg=cap)
             feats, labels = graph.feats, graph.labels
             pad = (-graph.n) % n_dev
             if pad:               # zero-weight rows aggregate to zero
@@ -597,10 +617,9 @@ class ShardedFullGraphSource(FullGraphSource):
             pcache = {}
             object.__setattr__(graph, "_featshard_plan_cache", pcache)
         if pkey not in pcache:
-            # one-resident-key eviction like the ELL cache: cached steps
-            # that closed over an evicted plan keep it alive themselves
+            # one-resident-key eviction like the ELL cache
             pcache.clear()
-            idx_h, w_h, _ = to_ell(graph, max_deg=self.max_deg)
+            idx_h, w_h, _ = to_ell(graph, max_deg=self._cap(cfg))
             pad = (-graph.n) % n_dev
             if pad:
                 idx_h = np.pad(idx_h, ((0, pad), (0, 0)))
@@ -629,11 +648,9 @@ class ShardedFullGraphSource(FullGraphSource):
                           cfg.n_classes)
 
     def loss_consts(self):
-        # the mesh and featshard plan ride along as (static, closed-over)
-        # consts so the forward can shard_map the kernel path over the
-        # NODES axis; sh.node_mesh() and the per-graph plan cache are
-        # memoized, keeping the step-cache key (which hashes the consts'
-        # identity) stable across binds
+        # the mesh (static) and the featshard plan (a pytree: arrays as
+        # step arguments, layout static) ride along so the forward can
+        # shard_map the kernel path over the NODES axis
         return tuple(self.ell) + (self.train_nodes, self._mesh,
                                   self.feats_plan)
 
@@ -1102,8 +1119,7 @@ class ShardedSampledSource(SampledSource):
                           valid=valid)
 
     def loss_consts(self):
-        # static closed-over mesh for the shard_map'd kernel path (the
-        # memoized sh.node_mesh keeps the step-cache key stable)
+        # the static mesh for the shard_map'd kernel path
         return (self._mesh,)
 
     def _row_sharding(self, ndim: int):
@@ -1498,8 +1514,11 @@ class Trainer:
         self._scfg = _static_cfg(cfg)
         # evaluation + full-loss tracking reuse the source's ELL when it
         # has one (FullGraphSource with max_deg: eval on the SAME capped
-        # adjacency the old loop used, and no second full-width upload)
-        self._ell = getattr(self.source, "ell", None) or _device_ell(graph)
+        # adjacency, and no second full-width upload); otherwise the
+        # config's ELL cap (a power-law graph's uncapped ELL is [n, d_max]
+        # with d_max in the tens of thousands)
+        self._ell = (getattr(self.source, "ell", None)
+                     or _device_ell(graph, cfg.max_degree))
         # sharded sources + kernel: eval/full-loss partition the Pallas
         # aggregation over the source's mesh too (the kernel cannot be
         # GSPMD-partitioned; einsum-path runs keep mesh=None so their
@@ -1514,14 +1533,15 @@ class Trainer:
             # built-in sources: one compiled step per (source type,
             # normalized cfg, optimizer spec, consts) PER GRAPH — shared
             # across every Trainer a sweep creates
-            self._step = _cached_step(graph, type(self.source),
-                                      self.source.loss_consts(), cfg,
-                                      plan)
+            self._step, self._step_arrays = _cached_step(
+                graph, type(self.source), self.source.loss_consts(), cfg,
+                plan)
         else:
             # custom source: per-Trainer jit over the instance loss
             src, opt = self.source, self.opt
+            self._step_arrays = ()
 
-            def step(params, opt_state, batch):
+            def step(params, opt_state, batch, arrs):
                 loss, grads = jax.value_and_grad(
                     lambda p: src.loss(p, batch))(params)
                 params, opt_state, good = _guarded_update(
@@ -1551,9 +1571,10 @@ class Trainer:
 
     def close(self) -> None:
         """Release device references held by this Trainer (the per-graph
-        ELL/step caches keep at most one resident entry; sweeps call
-        this between grid points)."""
+        ELL cache keeps at most one resident entry; sweeps call this
+        between grid points)."""
         self._ell = None
+        self._step_arrays = ()
         self.source.close()
 
     def _fire(self, hook: str, state: TrainState) -> None:
@@ -1702,7 +1723,7 @@ class Trainer:
                             "ignore",
                             message="Some donated buffers were not usable")
                     params, opt_state, loss, good = self._step(
-                        params, opt_state, batch)
+                        params, opt_state, batch, self._step_arrays)
                 # eval / tracked full loss are DISPATCHED here (device
                 # scalars); the floats are read in _consume
                 val = (self._eval_dev(params, val_sel)

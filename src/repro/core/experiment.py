@@ -23,7 +23,6 @@ import dataclasses
 import itertools
 import json
 import os
-import warnings
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -259,14 +258,6 @@ def _append_journal(path: str, rec: Dict) -> None:
         os.fsync(f.fileno())
 
 
-def _is_pallas_failure(e: BaseException) -> bool:
-    """Does this look like the Pallas/Mosaic aggregation kernel failing
-    to lower on this backend (as opposed to a training bug)?"""
-    s = f"{type(e).__name__}: {e}"
-    return any(m in s for m in ("Mosaic", "mosaic", "Pallas", "pallas",
-                                "Triton", "triton"))
-
-
 def sweep(graph: Graph, cfg: GNNConfig, plan: TrainPlan,
           batch_sizes: Sequence[int] = (),
           fanout_grid: Sequence[Sequence[int]] = (),
@@ -297,11 +288,9 @@ def sweep(graph: Graph, cfg: GNNConfig, plan: TrainPlan,
     (error points are retried on resume).  ``inference`` appends the
     serving-cost columns (``inference_metrics``) to every row, making
     the cube a (b, β, sampler, serving-cost) comparison — the paper
-    extension.  Independently of the journal,
-    a point whose Pallas aggregation kernel fails to lower is retried
-    once with ``use_agg_kernel=False`` (loud RuntimeWarning; the row
-    carries ``agg_kernel_degraded=True``) so one backend quirk does not
-    sink a long sweep.
+    extension.  A kernel that fails to lower is an error like any other:
+    it propagates (or, with a journal, becomes that point's error row);
+    no point is ever re-run on another aggregation path.
     """
     points: List[Tuple[str, Optional[int], Optional[Tuple[int, ...]]]] = []
     seen = set()
@@ -354,33 +343,10 @@ def sweep(graph: Graph, cfg: GNNConfig, plan: TrainPlan,
             # run_experiment owns the effective-(b, fanouts) validation
             # and fails fast on bad grid points (satellite)
             try:
-                try:
-                    row = run_experiment(graph, cfg, plan_pt,
-                                         paradigm=paradigm, b=b,
-                                         fanouts=fo, inference=inference,
-                                         serve_queries=serve_queries)
-                # Mosaic/Triton lowering failures surface as
-                # RuntimeError (XlaRuntimeError), NotImplementedError,
-                # or ValueError/TypeError from the pallas lowering
-                # rules — anything else is a training bug and must not
-                # enter the degrade path at all
-                except (RuntimeError, NotImplementedError, ValueError,
-                        TypeError) as e:
-                    if not (cfg.use_agg_kernel and _is_pallas_failure(e)):
-                        raise
-                    warnings.warn(
-                        f"Pallas aggregation kernel failed to lower for "
-                        f"point {key} ({type(e).__name__}: {e}) — "
-                        f"DEGRADING to the einsum path for this point "
-                        f"(use_agg_kernel=False); throughput rows from "
-                        f"it are NOT kernel-path numbers",
-                        RuntimeWarning, stacklevel=2)
-                    row = run_experiment(
-                        graph,
-                        dataclasses.replace(cfg, use_agg_kernel=False),
-                        plan_pt, paradigm=paradigm, b=b, fanouts=fo,
-                        inference=inference, serve_queries=serve_queries)
-                    row["agg_kernel_degraded"] = True
+                row = run_experiment(graph, cfg, plan_pt,
+                                     paradigm=paradigm, b=b,
+                                     fanouts=fo, inference=inference,
+                                     serve_queries=serve_queries)
             except Exception as e:
                 # deliberately broad: without a journal this sweep is
                 # interactive — fail fast.  With one it is a long
@@ -462,8 +428,9 @@ def main(argv: Optional[Sequence[str]] = None) -> List[Dict]:
     ap.add_argument("--fullgraph", action="store_true")
     ap.add_argument("--kernel", action="store_true",
                     help="run every grid point through the Pallas "
-                         "aggregation kernel (interpret mode — works on "
-                         "CPU and on multi-device meshes via shard_map)")
+                         "aggregation kernel (compiled on a TPU, "
+                         "interpreted elsewhere; multi-device meshes "
+                         "via shard_map)")
     ap.add_argument("--feats-layout", default="replicated",
                     choices=["replicated", "sharded"],
                     help="gather-source table layout for the kernel "
@@ -492,7 +459,7 @@ def main(argv: Optional[Sequence[str]] = None) -> List[Dict]:
                     feat_dim=graph.feats.shape[1], hidden=32,
                     n_classes=graph.n_classes, n_layers=args.layers,
                     fanout=(5,) * args.layers, batch_size=64, loss="ce",
-                    use_agg_kernel=args.kernel, agg_interpret=True,
+                    use_agg_kernel=args.kernel,
                     feats_layout=args.feats_layout,
                     feat_cache_rows=args.cache_rows)
     plan = TrainPlan(lr=args.lr, n_iters=args.iters,

@@ -69,12 +69,12 @@ def _kernel_agg(cfg: GNNConfig, table, idx, w, self_rows=None,
         from repro.kernels.neighbor_agg.ops import neighbor_agg_sharded
         return neighbor_agg_sharded(
             table, idx, w, self_rows, w_self, mesh=mesh,
-            interpret=cfg.agg_interpret, b_tile=cfg.agg_b_tile,
+            b_tile=cfg.agg_b_tile,
             d_tile=cfg.agg_d_tile, k_slab=cfg.agg_k_slab)
     from repro.kernels.neighbor_agg.ops import neighbor_agg
     return neighbor_agg(table, idx, w, self_rows, w_self,
                         use_kernel=True, kernel="tiled",
-                        interpret=cfg.agg_interpret, b_tile=cfg.agg_b_tile,
+                        b_tile=cfg.agg_b_tile,
                         d_tile=cfg.agg_d_tile, k_slab=cfg.agg_k_slab)
 
 
@@ -105,7 +105,7 @@ def _wsum(cfg: GNNConfig, w_edge, h_nb, h_self=None, w_self=None,
             w_edge.reshape(b, k), h_nb.reshape(b, k, d),
             h_self.reshape(b, d) if fused else None,
             w_self.reshape(b) if fused else None, mesh=mesh,
-            interpret=cfg.agg_interpret, b_tile=cfg.agg_b_tile,
+            b_tile=cfg.agg_b_tile,
             d_tile=cfg.agg_d_tile, k_slab=cfg.agg_k_slab)
         return out.reshape(lead + (d,))
     table = h_nb.reshape(-1, d)
@@ -237,7 +237,7 @@ def full_graph_forward(params, cfg: GNNConfig, feats, ell_idx, ell_w,
             from repro.kernels.neighbor_agg.ops import neighbor_agg_featshard
             return neighbor_agg_featshard(
                 srcr, w_edge.astype(agg_dt), feats_plan,
-                interpret=cfg.agg_interpret, b_tile=cfg.agg_b_tile,
+                b_tile=cfg.agg_b_tile,
                 d_tile=cfg.agg_d_tile,
                 k_slab=cfg.agg_k_slab).astype(h.dtype)
         if cfg.use_agg_kernel:
@@ -264,7 +264,7 @@ def full_graph_forward(params, cfg: GNNConfig, feats, ell_idx, ell_w,
                     agg = neighbor_agg_featshard(
                         srcr, ell_w.astype(agg_dt), feats_plan,
                         self_rows=srcr, w_self=w_self.astype(agg_dt),
-                        interpret=cfg.agg_interpret, b_tile=cfg.agg_b_tile,
+                        b_tile=cfg.agg_b_tile,
                         d_tile=cfg.agg_d_tile,
                         k_slab=cfg.agg_k_slab).astype(h.dtype)
                 else:

@@ -113,23 +113,31 @@ def to_ell(graph: Graph, max_deg: Optional[int] = None, rows=None
                          f"d_max={graph.d_max}), got {max_deg}")
     m = len(rows)
     deg_all = graph.degrees
-    nb, valid = neighbors_batch(graph, rows)          # [m, width]
-    deg = deg_all[np.asarray(rows, np.int64)]
-    cw = (1.0 / np.sqrt((deg[:, None] + 1.0) * (deg_all[nb] + 1.0))
+    rows64 = np.asarray(rows, np.int64)
+    deg = deg_all[rows64]
+    # flat CSR segments of the selected rows: one entry per edge, so the
+    # cost is O(nnz) however heavy the degree tail (a padded
+    # [m, d_max] block would not fit at power-law scale)
+    seg = np.repeat(np.arange(m, dtype=np.int64), deg)
+    first = np.cumsum(deg) - deg
+    pos = np.arange(seg.size, dtype=np.int64) - first[seg]
+    nb = graph.indices[graph.indptr[rows64][seg] + pos].astype(np.int32)
+    cw = (1.0 / np.sqrt((deg[seg] + 1.0) * (deg_all[nb] + 1.0))
           ).astype(np.float32)
-    cw[~valid] = 0.0
-    width = nb.shape[1]
-    if width > k:
-        # keep the K highest-weight neighbors per row (padding sorts last)
-        keep = np.argpartition(-cw, k - 1, axis=1)[:, :k]
-        nb = np.take_along_axis(nb, keep, axis=1)
-        cw = np.take_along_axis(cw, keep, axis=1)
-        valid = np.take_along_axis(valid, keep, axis=1)
-        nb[~valid] = 0
+    if seg.size and int(deg.max()) > k:
+        # rows wider than K keep their K highest-weight neighbors (CSR
+        # order among equal weights); narrower rows keep CSR order
+        wide = deg[seg] > k
+        rank = pos.copy()
+        sub = np.nonzero(wide)[0]
+        order = np.lexsort((pos[sub], -cw[sub], seg[sub]))
+        rank[sub[order]] = pos[sub]
+        keep = rank < k
+        seg, pos, nb, cw = seg[keep], rank[keep], nb[keep], cw[keep]
     idx = np.zeros((m, k), np.int32)
     w = np.zeros((m, k), np.float32)
-    idx[:, :min(width, k)] = nb[:, :k]
-    w[:, :min(width, k)] = cw[:, :k]
+    idx[seg, pos] = nb
+    w[seg, pos] = cw
     w_self = (1.0 / (deg + 1.0)).astype(np.float32)
     return idx, w, w_self
 

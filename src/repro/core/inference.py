@@ -130,7 +130,7 @@ def _chunk_apply(cfg: GNNConfig, last: bool, mesh, p, h, src, rows, idx,
     return out if last else jax.nn.relu(out)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+@functools.partial(jax.jit, static_argnums=(0, 1))
 def _featshard_layer(cfg: GNNConfig, last: bool, fsplan, p, h, w, w_self):
     """One FULL layer over the NODES-sharded table (feats_layout =
     "sharded"): no chunk loop and no replicated source anywhere — the
@@ -138,10 +138,10 @@ def _featshard_layer(cfg: GNNConfig, last: bool, fsplan, p, h, w, w_self):
     layer l+1 in place (the ISSUE's "layer tables stay NODES-sharded"
     serving requirement).  Mirrors ``full_graph_forward``'s gcn /
     graphsage bodies through ``neighbor_agg_featshard``; ``fsplan`` is
-    the identity-hashed static plan for THIS ell/mesh."""
+    the plan for THIS ell/mesh (a pytree argument)."""
     from repro.kernels.neighbor_agg.ops import neighbor_agg_featshard
     agg_dt = jnp.bfloat16 if cfg.dtype == "bfloat16" else h.dtype
-    kw = dict(interpret=cfg.agg_interpret, b_tile=cfg.agg_b_tile,
+    kw = dict(b_tile=cfg.agg_b_tile,
               d_tile=cfg.agg_d_tile, k_slab=cfg.agg_k_slab)
     if cfg.model == "gcn":
         wmat = p["w"]

@@ -20,9 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions
-_CompilerParams = getattr(pltpu, "TPUCompilerParams", None) \
-    or getattr(pltpu, "CompilerParams")
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -77,9 +75,11 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
 
 def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
                            q_block: int = 128, k_block: int = 128,
-                           interpret: bool = True):
+                           interpret=None):
     """q,k,v: [B, H, S, D] -> [B, H, S, D].  causal must be True (the
-    decoder case); window>0 adds sliding-window banding."""
+    decoder case); window>0 adds sliding-window banding.  interpret=None
+    compiles on a TPU backend and interprets on any other."""
+    interpret = resolve_interpret(interpret)
     assert causal, "kernel is causal-only (decoder attention)"
     b, h, s, d = q.shape
     q_block = min(q_block, s)
@@ -110,7 +110,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((q_block,), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(qf, kf, vf)
     return out.reshape(b, h, s, d)

@@ -16,7 +16,7 @@ from repro.kernels.flash_attn.ref import flash_attention_ref
                                              "interpret", "q_block",
                                              "k_block"))
 def flash_attention(q, k, v, *, window: int = 0, use_kernel: bool = False,
-                    interpret: bool = True, q_block: int = 128,
+                    interpret=None, q_block: int = 128,
                     k_block: int = 128):
     """q: [B, S, Hq, D]; k,v: [B, S, Hkv, D] (GQA-expanded internally).
     Causal (+ optional sliding window).  Returns [B, S, Hq, D]."""

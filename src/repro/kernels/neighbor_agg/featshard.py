@@ -29,14 +29,16 @@ Per-device table memory drops from ``O(n·d)`` to
 per call is ``(S-1)·(M + C_max)`` rows (``remote_bytes_per_call``).
 
 The plan is STATIC per (graph ELL, mesh, C): all index remapping happens
-once at bind time on the host (``build_featshard_plan``); the op closes
-over the resulting device arrays like the engine closes over its ELL
-consts.  On a 1-device mesh every reference is hot or local and the miss
+once at bind time on the host (``build_featshard_plan``).  The resulting
+plan is a pytree: its device index arrays are leaves, passed to the op
+(and to every jitted function that takes the plan) as arguments, and its
+layout (mesh, sizes) is static.  On a 1-device mesh every reference is hot or local and the miss
 set is empty, so the op is bit-identical to the unsharded tiled kernel —
 forward AND gradients (test-enforced, tests/test_featshard.py).
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -173,19 +175,28 @@ def _plan_arrays(idx, w, degrees, n_shards: int, cache_rows: int) -> dict:
 # Device-resident plan
 # ---------------------------------------------------------------------------
 
+#: the plan's device index arrays (pytree leaves; None where unused)
+_PLAN_ARRAYS = ("lidx_hot", "hot_mask", "lidx_miss", "serve_loc",
+                "hot_src_loc", "hot_slot", "hot_valid", "hot_perm")
+#: the plan's static layout (pytree aux data, hashed by jit)
+_PLAN_META = ("S", "n", "n_pad", "n_loc", "K", "C", "M", "C_max")
+
+
+@jax.tree_util.register_pytree_node_class
 class FeatShardPlan:
     """Device-resident featshard plan for one (graph ELL, mesh, C).
 
-    Deliberately a plain class with identity hash/eq: the plan rides jit
-    STATIC arguments (``_eval_acc``) while its device index arrays are
-    closed over by the op like the engine's ELL consts — both require a
-    stable identity, which the sources' bind-time caches provide.
+    A pytree: the index arrays are leaves, so a jitted function takes
+    the plan as an ordinary argument (the arrays stay device buffers,
+    never executable constants), and the mesh plus the sizes are its
+    static aux data, so two plans of the same layout share one trace.
+    The host-side ``hot_ids``/``stats`` do not cross a jit boundary.
     """
 
     def __init__(self, mesh, host: dict):
         from repro import sharding as sh
         self.mesh = mesh
-        for k in ("S", "n", "n_pad", "n_loc", "K", "C", "M", "C_max"):
+        for k in _PLAN_META:
             setattr(self, k, host[k])
         self.hot_ids = host["hot_ids"]
         self.stats = dict(host["stats"])
@@ -207,9 +218,24 @@ class FeatShardPlan:
         else:
             self.hot_src_loc = self.hot_slot = None
             self.hot_valid = self.hot_perm = None
-        self._ops: dict = {}
 
-    # -- bind-time accounting (ISSUE 8 acceptance: per-device bytes) ---
+    def tree_flatten(self):
+        return (tuple(getattr(self, a) for a in _PLAN_ARRAYS),
+                (self.mesh,) + tuple(int(getattr(self, k))
+                                     for k in _PLAN_META))
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        plan = object.__new__(cls)
+        plan.mesh = aux[0]
+        for k, v in zip(_PLAN_META, aux[1:]):
+            setattr(plan, k, v)
+        for a, v in zip(_PLAN_ARRAYS, children):
+            setattr(plan, a, v)
+        plan.hot_ids, plan.stats = None, {}
+        return plan
+
+    # -- bind-time accounting: per-device bytes -------------------------
     def table_bytes_per_device(self, d: int, itemsize: int = 4) -> int:
         """Resident gather-source bytes per device: the local row block
         plus the replicated hot cache — n·d/S + C·d, NOT n·d."""
@@ -219,14 +245,6 @@ class FeatShardPlan:
         """Bytes received per device per aggregation call (compacted
         serve all_gather + hot-cache fill)."""
         return self.stats["remote_rows_per_call"] * d * itemsize
-
-    def _op(self, static, fused: bool):
-        key = (static, fused)
-        op = self._ops.get(key)
-        if op is None:
-            op = _make_op(self, static, fused)
-            self._ops[key] = op
-        return op
 
 
 def build_featshard_plan(idx, w, degrees, mesh,
@@ -243,28 +261,36 @@ def build_featshard_plan(idx, w, degrees, mesh,
 # The two-phase op (shard_map + manual custom VJP)
 # ---------------------------------------------------------------------------
 
-def _make_op(plan: FeatShardPlan, static, fused: bool):
+@functools.lru_cache(maxsize=None)
+def _make_op(mesh, has_miss: bool, C: int, static, fused: bool):
+    """The custom-VJP op for one plan layout.  The plan's index arrays
+    are its last operand, so they enter every jitted caller as
+    arguments; their cotangent is None."""
     from repro import sharding as sh
     from repro.kernels.neighbor_agg.ops import _tiled_call, _tiled_grads
 
-    mesh = plan.mesh
     ax = sh.nodes_axis(mesh)
     row2, row1, repl1 = P(ax, None), P(ax), P(None)
-    has_miss = plan.M > 0
-    has_hot = plan.C > 0
-    C = plan.C
+    has_hot = C > 0
 
-    aux = (plan.lidx_hot,)
     aux_specs = (row2,)
     if has_miss:
-        aux += (plan.hot_mask, plan.lidx_miss, plan.serve_loc)
         aux_specs += (row2, row2, row2)
     if has_hot:
-        aux += (plan.hot_src_loc, plan.hot_perm)
         aux_specs += (row2, repl1)
     # the VJP additionally needs the hot scatter-back maps
-    baux = aux + ((plan.hot_slot, plan.hot_valid) if has_hot else ())
     baux_specs = aux_specs + ((row2, row2) if has_hot else ())
+
+    def _aux(arrs, with_back):
+        a = dict(zip(_PLAN_ARRAYS, arrs))
+        out = (a["lidx_hot"],)
+        if has_miss:
+            out += (a["hot_mask"], a["lidx_miss"], a["serve_loc"])
+        if has_hot:
+            out += (a["hot_src_loc"], a["hot_perm"])
+            if with_back:
+                out += (a["hot_slot"], a["hot_valid"])
+        return out
 
     def _unpack(rest, with_back):
         it = iter(rest)
@@ -314,7 +340,8 @@ def _make_op(plan: FeatShardPlan, static, fused: bool):
             out = _tiled_call(gathered, lm, w2, out, ones, static)
         return out
 
-    def _fwd(feats, w, self_rows, w_self):
+    def _fwd(feats, w, self_rows, w_self, arrs):
+        aux = _aux(arrs, False)
         ops_in = (feats, w) + ((self_rows, w_self) if fused else ())
         specs = (row2, row2) + ((row2, row1) if fused else ())
 
@@ -328,9 +355,9 @@ def _make_op(plan: FeatShardPlan, static, fused: bool):
         return sh.shard_map(local, mesh, specs + aux_specs,
                             row2)(*ops_in, *aux)
 
-    def _bwd(feats, w, self_rows, w_self, g):
+    def _bwd(feats, w, self_rows, w_self, arrs, g):
         ops_in = ((feats, w) + ((self_rows, w_self) if fused else ())
-                  + baux + (g,))
+                  + _aux(arrs, True) + (g,))
         specs = ((row2, row2) + ((row2, row1) if fused else ())
                  + baux_specs + (row2,))
         out_specs = (row2, row2) + ((row2, row1) if fused else ())
@@ -375,25 +402,24 @@ def _make_op(plan: FeatShardPlan, static, fused: bool):
         return sh.shard_map(local, mesh, specs, out_specs)(*ops_in)
 
     @jax.custom_vjp
-    def op(feats, w, self_rows, w_self):
-        return _fwd(feats, w, self_rows, w_self)
+    def op(feats, w, self_rows, w_self, arrs):
+        return _fwd(feats, w, self_rows, w_self, arrs)
 
-    def op_fwd(feats, w, self_rows, w_self):
-        return _fwd(feats, w, self_rows, w_self), (feats, w, self_rows,
-                                                   w_self)
+    def op_fwd(feats, w, self_rows, w_self, arrs):
+        return (_fwd(feats, w, self_rows, w_self, arrs),
+                (feats, w, self_rows, w_self, arrs))
 
     def op_bwd(res, g):
-        grads = _bwd(*res, g)
-        return tuple(grads) if fused else tuple(grads) + (None, None)
+        grads = tuple(_bwd(*res, g))
+        return (grads if fused else grads + (None, None)) + (None,)
 
     op.defvjp(op_fwd, op_bwd)
     return op
 
 
 def neighbor_agg_featshard(feats, w, plan: FeatShardPlan, self_rows=None,
-                           w_self=None, *, interpret: bool = True,
-                           d_tile: int = 128, b_tile: int = 8,
-                           k_slab: int = 4):
+                           w_self=None, *, d_tile: int = 128,
+                           b_tile: int = 8, k_slab: int = 4):
     """``out[b] = Σ_k w[b,k]·feats[idx[b,k]] [+ w_self[b]·self_rows[b]]``
     with the SOURCE TABLE row-sharded over the plan's NODES mesh (no
     replicated [n, d] copy anywhere): phase-1 tiled Pallas aggregation
@@ -417,6 +443,7 @@ def neighbor_agg_featshard(feats, w, plan: FeatShardPlan, self_rows=None,
             f"w {w.shape}) do not match the plan "
             f"(n_pad={plan.n_pad}, K={plan.K}) — rebuild the plan for "
             f"this ELL/mesh")
-    static = ("tiled", bool(interpret), int(d_tile), int(b_tile),
-              int(k_slab))
-    return plan._op(static, fused)(feats, w, self_rows, w_self)
+    static = ("tiled", int(d_tile), int(b_tile), int(k_slab))
+    arrs = tuple(getattr(plan, a) for a in _PLAN_ARRAYS)
+    op = _make_op(plan.mesh, plan.M > 0, int(plan.C), static, fused)
+    return op(feats, w, self_rows, w_self, arrs)

@@ -1,10 +1,11 @@
 """jit'd public wrapper for the neighbor-aggregation kernels.
 
-Handles B/D/K padding to the kernel tile shape, dtype plumbing, the
-kernel / pure-jnp dispatch (the jnp path is what the 512-device dry-run
-lowers; the Pallas path targets real TPUs and is validated in interpret
-mode), and a custom VJP so BOTH training paths (full-graph GD and
-mini-batch SGD) can differentiate through the kernel:
+Handles B/K/D padding to the kernel tile shape, dtype plumbing, the
+kernel / pure-jnp dispatch, and a custom VJP so BOTH training paths
+(full-graph GD and mini-batch SGD) can differentiate through the
+kernel.  The kernel compiles with Mosaic on a TPU backend and runs in
+the Pallas interpreter on any other (``repro.kernels.default_interpret``;
+no caller chooses).  The gradients:
 
     d/dfeats = scatter-add of w[b,k] * g[b]   (segment-sum over idx)
     d/dw     = <g[b], feats[idx[b,k]]>
@@ -29,18 +30,44 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.neighbor_agg.neighbor_agg import (
-    neighbor_agg_pallas, neighbor_agg_pallas_tiled)
+    lanes_per_row, neighbor_agg_pallas, neighbor_agg_pallas_tiled)
 from repro.kernels.neighbor_agg.ref import neighbor_agg_ref
 
 
-def _run_kernel(feats, idx, w, static):
-    kernel, interpret, d_tile, b_tile, k_slab = static
+def _kernel_width(feats, static) -> int:
+    """Columns the kernel's D pads to.  The tiled kernel gathers rows
+    of 32-bit words (an f32 column or two bf16 columns per word): a row
+    of at most half a lane tile pads to exactly half a tile, which the
+    kernel pairs two rows per tile; a wider row pads to whole tiles."""
+    kernel, d_tile = static[:2]
+    d = feats.shape[1]
     if kernel == "row":
-        return neighbor_agg_pallas(feats, idx, w, d_tile=d_tile,
-                                   interpret=interpret)
-    return neighbor_agg_pallas_tiled(feats, idx, w, b_tile=b_tile,
-                                     d_tile=d_tile, k_slab=k_slab,
-                                     interpret=interpret)
+        return -(-d // d_tile) * d_tile
+    lanes = lanes_per_row(feats.dtype)
+    words = -(-d // lanes)
+    if d_tile % 2 == 0 and 2 * words <= d_tile:
+        return d_tile // 2 * lanes
+    return -(-words // d_tile) * d_tile * lanes
+
+
+def _pad_cols(x, width):
+    return x if x.shape[1] == width else jnp.pad(
+        x, ((0, 0), (0, width - x.shape[1])))
+
+
+def _run_kernel(feats, idx, w, static):
+    # D pads here, inside the custom VJP, so its residuals and backward
+    # work at the true width (padding bf16 172 -> 256 columns would
+    # inflate every backward buffer by half)
+    kernel, d_tile, b_tile, k_slab = static
+    d = feats.shape[1]
+    feats_p = _pad_cols(feats, _kernel_width(feats, static))
+    if kernel == "row":
+        out = neighbor_agg_pallas(feats_p, idx, w, d_tile=d_tile)
+    else:
+        out = neighbor_agg_pallas_tiled(feats_p, idx, w, b_tile=b_tile,
+                                        d_tile=d_tile, k_slab=k_slab)
+    return out[:, :d]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -83,11 +110,14 @@ _agg.defvjp(_agg_fwd, _agg_bwd)
 # (the epilogue folds into the accumulator init; see neighbor_agg.py)
 
 def _run_kernel_fused(feats, idx, w, self_rows, w_self, static):
-    _, interpret, d_tile, b_tile, k_slab = static
-    return neighbor_agg_pallas_tiled(feats, idx, w, self_rows=self_rows,
-                                     w_self=w_self, b_tile=b_tile,
-                                     d_tile=d_tile, k_slab=k_slab,
-                                     interpret=interpret)
+    _, d_tile, b_tile, k_slab = static
+    d = feats.shape[1]
+    width = _kernel_width(feats, static)
+    out = neighbor_agg_pallas_tiled(
+        _pad_cols(feats, width), idx, w,
+        self_rows=_pad_cols(self_rows, width), w_self=w_self,
+        b_tile=b_tile, d_tile=d_tile, k_slab=k_slab)
+    return out[:, :d]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
@@ -127,20 +157,19 @@ def _tiled_call(feats, idx, w, self_rows, w_self, static):
     """Tile-pad + tiled-kernel dispatch, shared by the jit wrapper below
     and the shard-local bodies of the sharded entry points (the padding
     must be IDENTICAL in both so the sharded path stays bit-equal to the
-    unsharded one on a 1-device mesh)."""
-    _, _, d_tile, b_tile, k_slab = static
-    b, k = idx.shape
-    d = feats.shape[1]
-    feats_p = _pad_to(feats, 1, d_tile)
+    unsharded one on a 1-device mesh).  B and K pad here; D pads inside
+    the kernel call (``_run_kernel``)."""
+    _, d_tile, b_tile, k_slab = static
+    b = idx.shape[0]
     idx_p = _pad_to(_pad_to(idx, 0, b_tile), 1, k_slab)
     w_p = _pad_to(_pad_to(w, 0, b_tile), 1, k_slab)
     if self_rows is not None:
-        self_p = _pad_to(_pad_to(self_rows, 0, b_tile), 1, d_tile)
+        self_p = _pad_to(self_rows, 0, b_tile)
         wself_p = _pad_to(w_self, 0, b_tile)
-        out = _agg_self(feats_p, idx_p, w_p, self_p, wself_p, static)
+        out = _agg_self(feats, idx_p, w_p, self_p, wself_p, static)
     else:
-        out = _agg(feats_p, idx_p, w_p, static)
-    return out[:b, :d]
+        out = _agg(feats, idx_p, w_p, static)
+    return out[:b]
 
 
 def _tiled_grads(static, feats, idx, w, self_rows, w_self, g):
@@ -150,29 +179,25 @@ def _tiled_grads(static, feats, idx, w, self_rows, w_self, g):
     sharded entry points is bit-identical to the unsharded kernel
     path's.  Returns ``(dfeats, dw, dself_rows, dw_self)`` (the last
     two ``None`` when not fused)."""
-    _, _, d_tile, b_tile, k_slab = static
+    _, d_tile, b_tile, k_slab = static
     b, k = idx.shape
-    d = feats.shape[1]
-    feats_p = _pad_to(feats, 1, d_tile)
     idx_p = _pad_to(_pad_to(idx, 0, b_tile), 1, k_slab)
     w_p = _pad_to(_pad_to(w, 0, b_tile), 1, k_slab)
-    g_p = _pad_to(_pad_to(g, 0, b_tile), 1, d_tile)
+    g_p = _pad_to(g, 0, b_tile)
     if self_rows is not None:
-        self_p = _pad_to(_pad_to(self_rows, 0, b_tile), 1, d_tile)
+        self_p = _pad_to(self_rows, 0, b_tile)
         wself_p = _pad_to(w_self, 0, b_tile)
         df, _, dw, dself, dwself = _agg_self_bwd(
-            static, (feats_p, idx_p, w_p, self_p, wself_p), g_p)
-        return df[:, :d], dw[:b, :k], dself[:b, :d], dwself[:b]
-    df, _, dw = _agg_bwd(static, (feats_p, idx_p, w_p), g_p)
-    return df[:, :d], dw[:b, :k], None, None
+            static, (feats, idx_p, w_p, self_p, wself_p), g_p)
+        return df, dw[:b, :k], dself[:b], dwself[:b]
+    df, _, dw = _agg_bwd(static, (feats, idx_p, w_p), g_p)
+    return df, dw[:b, :k], None, None
 
 
-@functools.partial(jax.jit, static_argnames=("use_kernel", "interpret",
-                                             "kernel", "d_tile", "b_tile",
-                                             "k_slab"))
+@functools.partial(jax.jit, static_argnames=("use_kernel", "kernel",
+                                             "d_tile", "b_tile", "k_slab"))
 def neighbor_agg(feats, idx, w, self_rows=None, w_self=None, *,
-                 use_kernel: bool = False,
-                 interpret: bool = True, kernel: str = "tiled",
+                 use_kernel: bool = False, kernel: str = "tiled",
                  d_tile: int = 128, b_tile: int = 8, k_slab: int = 4):
     """out[b] = Σ_k w[b,k] · feats[idx[b,k]]  [+ w_self[b] · self_rows[b]].
 
@@ -191,11 +216,9 @@ def neighbor_agg(feats, idx, w, self_rows=None, w_self=None, *,
     if not use_kernel:
         out = neighbor_agg_ref(feats, idx, w)
         return out + w_self[:, None] * self_rows if fused else out
-    b, k = idx.shape
-    d = feats.shape[1]
-    static = (kernel, interpret, d_tile, b_tile, k_slab)
+    static = (kernel, d_tile, b_tile, k_slab)
     if kernel == "row":
-        out = _agg(_pad_to(feats, 1, d_tile), idx, w, static)[:b, :d]
+        out = _agg(feats, idx, w, static)
         return out + w_self[:, None] * self_rows if fused else out
     # padded rows carry w_self = 0, so the fused epilogue stays exact
     return _tiled_call(feats, idx, w, self_rows if fused else None,
@@ -268,8 +291,8 @@ _agg_sharded.defvjp(_agg_sharded_fwd, _agg_sharded_bwd)
 
 def neighbor_agg_sharded(feats, idx, w, self_rows=None, w_self=None, *,
                          mesh=None, use_kernel: bool = True,
-                         interpret: bool = True, d_tile: int = 128,
-                         b_tile: int = 8, k_slab: int = 4):
+                         d_tile: int = 128, b_tile: int = 8,
+                         k_slab: int = 4):
     """``out[b] = Σ_k w[b,k]·feats[idx[b,k]] [+ w_self[b]·self_rows[b]]``
     partitioned over the NODES axis of ``mesh``: output rows / ``idx`` /
     ``w`` / ``self_rows`` / ``w_self`` shard their leading axis, the
@@ -288,9 +311,8 @@ def neighbor_agg_sharded(feats, idx, w, self_rows=None, w_self=None, *,
         "self_rows and w_self must be passed together"
     if mesh is None or not use_kernel:
         return neighbor_agg(feats, idx, w, self_rows, w_self,
-                            use_kernel=use_kernel, interpret=interpret,
-                            kernel="tiled", d_tile=d_tile, b_tile=b_tile,
-                            k_slab=k_slab)
+                            use_kernel=use_kernel, kernel="tiled",
+                            d_tile=d_tile, b_tile=b_tile, k_slab=k_slab)
     from repro import sharding as sh
     b = idx.shape[0]
     n_sh = sh.nodes_shards(mesh)
@@ -299,7 +321,7 @@ def neighbor_agg_sharded(feats, idx, w, self_rows=None, w_self=None, *,
     if fused:
         self_rows = _pad_to(self_rows, 0, n_sh)
         w_self = _pad_to(w_self, 0, n_sh)
-    static = ("tiled", interpret, d_tile, b_tile, k_slab)
+    static = ("tiled", d_tile, b_tile, k_slab)
     out = _agg_sharded(feats, idx, w, self_rows, w_self, (mesh, static))
     return out[:b] if out.shape[0] != b else out
 
@@ -380,8 +402,8 @@ from repro.kernels.neighbor_agg.featshard import (  # noqa: E402
 
 
 def neighbor_agg_batch_sharded(w, h_nb, h_self=None, w_self=None, *, mesh,
-                               interpret: bool = True, d_tile: int = 128,
-                               b_tile: int = 8, k_slab: int = 4):
+                               d_tile: int = 128, b_tile: int = 8,
+                               k_slab: int = 4):
     """Tiled-kernel weighted sum over an ALREADY-GATHERED fan-out level
     (``h_nb [B, K, D]``, ``w [B, K]`` [+ fused ``h_self [B, D]`` /
     ``w_self [B]``]) with the target rows sharded over NODES: each shard
@@ -401,5 +423,5 @@ def neighbor_agg_batch_sharded(w, h_nb, h_self=None, w_self=None, *, mesh,
             f"neighbor_agg_batch_sharded: B={w.shape[0]} must be a "
             f"multiple of the {n_sh} NODES shards (the sharded sources "
             f"round b up to a mesh multiple at bind)")
-    static = ("tiled", interpret, d_tile, b_tile, k_slab)
+    static = ("tiled", d_tile, b_tile, k_slab)
     return _agg_batch_sharded(w, h_nb, h_self, w_self, (mesh, static))

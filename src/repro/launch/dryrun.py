@@ -61,8 +61,6 @@ def _mem_dict(ma) -> Dict[str, int]:
 def _finish(lowered, t0, extra: Dict[str, Any]) -> Dict[str, Any]:
     compiled = lowered.compile()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):       # jax <= 0.4.x returns [dict]
-        ca = ca[0] if ca else {}
     ma = compiled.memory_analysis()
     mem = _mem_dict(ma)
     hlo = compiled.as_text()
@@ -139,11 +137,11 @@ def dryrun_gnn(arch: str, gnn_shape: str, multi_pod: bool) -> Dict[str, Any]:
     import dataclasses
     cfg = get_config(arch)
     if getattr(cfg, "use_agg_kernel", False):
-        # the dry-run compiles on the CPU backend: the non-interpret
-        # Pallas gather only lowers through Mosaic on real TPUs, so the
+        # the dry-run's 512 devices are virtual CPU devices, where the
+        # Pallas gather could only lower through the interpreter, so the
         # roofline numbers here come from the (collective-equivalent)
-        # einsum path — the kernel itself is exercised by the interpret
-        # tests/bench and on hardware
+        # einsum path.  The kernel path itself is compiled for a
+        # described v5e in tests/test_tpu_compile.py.
         cfg = dataclasses.replace(cfg, use_agg_kernel=False)
     mesh = make_production_mesh(multi_pod=multi_pod)
     t0 = time.time()

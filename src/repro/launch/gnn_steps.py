@@ -51,6 +51,9 @@ def make_fullgraph_step(cfg: GNNConfig):
 
 def fullgraph_input_specs(cfg: GNNConfig, mesh) -> Tuple[Any, ...]:
     n, k, r = cfg.n_nodes, cfg.max_degree, cfg.feat_dim
+    if k is None:
+        raise ValueError(f"{cfg.name}: the full-graph step's ELL shape "
+                         f"needs cfg.max_degree")
     f32, i32 = jnp.float32, jnp.int32
     sds = lambda shp, dt, spec: jax.ShapeDtypeStruct(
         shp, dt, sharding=sh.named(spec, mesh))

@@ -14,12 +14,7 @@ import jax
 
 
 def _mesh_kwargs(n_axes: int) -> dict:
-    """jax >= 0.5 takes axis_types (AxisType.Auto); older jax (the pinned
-    0.4.x) has neither the kwarg nor the enum — Auto is its only mode."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

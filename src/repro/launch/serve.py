@@ -39,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import get_config
+from repro.launch.cache import enable_compile_cache
 
 
 # ---------------------------------------------------------------------------
@@ -59,8 +60,7 @@ def serve_gnn(args, cfg) -> int:
     graph = make_preset(args.preset, n=args.nodes, seed=args.seed)
     cfg = dataclasses.replace(
         cfg, n_nodes=graph.n, feat_dim=graph.feats.shape[1],
-        n_classes=graph.n_classes, use_agg_kernel=args.kernel,
-        agg_interpret=True)
+        n_classes=graph.n_classes, use_agg_kernel=args.kernel)
     params = G.init_gnn(jax.random.key(args.seed), cfg,
                         graph.feats.shape[1])
 
@@ -309,6 +309,7 @@ def main(argv=None):
     ap.add_argument("--kernel", action="store_true",
                     help="route gnn aggregation through the Pallas kernel")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if cfg.family == "gnn":

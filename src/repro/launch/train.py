@@ -24,6 +24,7 @@ from repro import sharding as sh
 from repro.checkpoint import save_checkpoint
 from repro.configs.base import get_config
 from repro.data import make_preset, token_batches
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 
 
@@ -163,6 +164,7 @@ def main():
                     help="GNN sweeps: JSONL completion journal for "
                          "crash-safe resume (see core.experiment.sweep)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if cfg.family == "gnn":

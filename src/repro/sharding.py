@@ -127,8 +127,8 @@ def node_mesh(n_devices: Optional[int] = None) -> Mesh:
     training; see engine.ShardedFullGraphSource).
 
     Memoized per device count: repeated binds (every sweep grid point
-    re-binds its source) must hand back the SAME Mesh object, so step
-    caches keyed on the closed-over constants' identity keep hitting."""
+    re-binds its source) hand back the SAME Mesh object, the static
+    part of every cached step's key."""
     return _node_mesh_cached(len(jax.devices()) if n_devices is None
                              else n_devices)
 
@@ -143,16 +143,11 @@ def row_sharding(mesh: Mesh, ndim: int) -> NamedSharding:
 # --- NODES-partitioned kernels (shard_map) ---------------------------------
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """Version-compat shard_map (``jax.shard_map``/``check_vma`` on new
-    jax, ``jax.experimental.shard_map``/``check_rep`` on 0.4.x) with
-    replication checking OFF: the neighbor-agg kernels place their psum
-    explicitly in the custom VJP (see kernels/README.md "Sharding")."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` with replication checking OFF: the neighbor-agg
+    kernels place their psum explicitly in the custom VJP (see
+    kernels/README.md "Sharding")."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def nodes_axis(mesh: Mesh):
@@ -210,18 +205,10 @@ def feats_spec(mesh: Mesh, layout: str = "replicated") -> P:
 
 def constrain(x, logical: Sequence[Optional[str]]):
     """with_sharding_constraint against the activated mesh; no-op when no
-    mesh is active (smoke tests) or when the spec can't bind to the
-    active mesh."""
+    mesh is active (smoke tests).  A spec that cannot bind to the active
+    mesh raises: a constraint that silently does nothing would leave the
+    layout to chance on a real mesh."""
     if _ACTIVE_MESH is None:
         return x
-    try:
-        spec = resolve(logical, _ACTIVE_MESH)
-        return jax.lax.with_sharding_constraint(x, spec)
-    except (ValueError, TypeError):
-        # jax 0.4.x raises ValueError when the resolved spec names a mesh
-        # axis the active mesh doesn't have (smoke meshes without a
-        # "model" axis) or when the spec's rank disagrees with the array;
-        # jax >= 0.5 surfaces sharding/axis-type mismatches from the new
-        # mesh machinery as TypeError.  Anything else (tracer leaks,
-        # internal errors) should propagate, not be eaten.
-        return x
+    return jax.lax.with_sharding_constraint(x, resolve(logical,
+                                                       _ACTIVE_MESH))

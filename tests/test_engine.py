@@ -36,6 +36,10 @@ def _cfg(g, **kw):
 # ---------------------------------------------------------------------------
 
 def _assert_matches(gold, res, name):
+    """Exact equality on every field.  The goldens were recorded on the
+    CPU backend with the installed jax (their "_rule" entry names the
+    version): a jax upgrade that changes the random bits is a reason to
+    re-record them, never to loosen this comparison."""
     h = res.history
     assert h.losses == gold["losses"], name
     assert h.val_accs == gold["val_accs"], name

@@ -165,8 +165,9 @@ def test_source_close_is_idempotent():
 
 def test_fn_cache_evicts_stale_consts_entries():
     """A sweep over distinct max_deg re-uploads the ELL; the per-graph
-    compiled-fn cache must drop the closure pinning the OLD upload when
-    the same logical step is rebuilt over the new one."""
+    compiled-fn cache keys hold no array identity, so both grid points
+    share ONE step entry (jit compiles once per ELL shape) and no entry
+    pins an old upload."""
     g = _fresh_graph(seed=23)
     cfg = _cfg(g)
     plan = TrainPlan(lr=0.3, n_iters=2, seed=0)
@@ -211,8 +212,7 @@ def test_sharded_fullgraph_row_shards_over_nodes_axis():
 
 def test_sharded_fullgraph_memoizes_uploads_across_trainers():
     """Sweep grid points over the sharded paradigm must reuse ONE
-    device upload — and therefore one compiled step (the step cache
-    keys on the consts' identity)."""
+    device upload and one compiled step."""
     g = _fresh_graph(seed=24)
     cfg = _cfg(g)
     plan = TrainPlan(lr=0.3, n_iters=2, seed=0)

@@ -39,7 +39,7 @@ from repro.kernels.neighbor_agg.ops import (build_featshard_plan,
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-KW = dict(interpret=True, d_tile=8, b_tile=4, k_slab=2)
+KW = dict(d_tile=8, b_tile=4, k_slab=2)
 
 
 def _cfg(g, **kw):
@@ -47,7 +47,7 @@ def _cfg(g, **kw):
                 feat_dim=g.feats.shape[1], hidden=16,
                 n_classes=g.n_classes, n_layers=2, fanout=(4, 3),
                 batch_size=32, loss="ce", use_agg_kernel=True,
-                agg_interpret=True, agg_b_tile=4, agg_d_tile=8,
+                agg_b_tile=4, agg_d_tile=8,
                 agg_k_slab=2)
     base.update(kw)
     return GNNConfig(**base)
@@ -317,7 +317,7 @@ from repro.kernels.neighbor_agg.ops import (build_featshard_plan,
                                             neighbor_agg_sharded)
 
 mesh = sh.node_mesh()
-KW = dict(interpret=True, d_tile=8, b_tile=4, k_slab=2)
+KW = dict(d_tile=8, b_tile=4, k_slab=2)
 
 # -- op level: fwd + grads vs the einsum reference, C auto and 0 ------------
 rng = np.random.default_rng(0)
@@ -366,7 +366,7 @@ g = make_sbm_graph(n=120, n_classes=4, avg_degree=8, feat_dim=16, seed=5)
 base = GNNConfig(name="fsmd", model="gcn", n_nodes=g.n, feat_dim=16,
                  hidden=16, n_classes=g.n_classes, n_layers=2,
                  fanout=(4, 3), batch_size=32, loss="ce",
-                 use_agg_kernel=True, agg_interpret=True, agg_b_tile=4,
+                 use_agg_kernel=True, agg_b_tile=4,
                  agg_d_tile=8, agg_k_slab=2)
 plan = TrainPlan(lr=0.3, n_iters=3, eval_every=2, seed=0)
 for model in ("gcn", "graphsage"):

@@ -34,7 +34,7 @@ def _cfg(g, **kw):
                 feat_dim=g.feats.shape[1], hidden=8,
                 n_classes=g.n_classes, n_layers=2, fanout=(4, 3),
                 batch_size=32, loss="ce", use_agg_kernel=False,
-                agg_interpret=True, agg_b_tile=4, agg_d_tile=8,
+                agg_b_tile=4, agg_d_tile=8,
                 agg_k_slab=2)
     base.update(kw)
     return GNNConfig(**base)
@@ -165,7 +165,7 @@ for model in ("gcn", "graphsage"):
                      hidden=8, n_classes=g.n_classes, n_layers=2,
                      fanout=(4, 3), batch_size=30, loss="ce")
     kcfg = dataclasses.replace(base, use_agg_kernel=True,
-                               agg_interpret=True, agg_b_tile=4,
+                               agg_b_tile=4,
                                agg_d_tile=8, agg_k_slab=2)
     params = G.init_gnn(jax.random.key(0), kcfg, 16)
     _, want = G.full_graph_forward(params, base, jnp.asarray(g.feats),

@@ -32,7 +32,7 @@ from repro.kernels.neighbor_agg.ops import (neighbor_agg,
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-KW = dict(interpret=True, d_tile=8, b_tile=4, k_slab=2)
+KW = dict(d_tile=8, b_tile=4, k_slab=2)
 
 
 def _cfg(g, **kw):
@@ -40,7 +40,7 @@ def _cfg(g, **kw):
                 feat_dim=g.feats.shape[1], hidden=16,
                 n_classes=g.n_classes, n_layers=2, fanout=(4, 3),
                 batch_size=32, loss="ce", use_agg_kernel=True,
-                agg_interpret=True, agg_b_tile=4, agg_d_tile=8,
+                agg_b_tile=4, agg_d_tile=8,
                 agg_k_slab=2)
     base.update(kw)
     return GNNConfig(**base)
@@ -195,7 +195,7 @@ from repro.kernels.neighbor_agg.ops import (neighbor_agg_batch_sharded,
                                             neighbor_agg_sharded)
 
 mesh = sh.node_mesh()
-KW = dict(interpret=True, d_tile=8, b_tile=4, k_slab=2)
+KW = dict(d_tile=8, b_tile=4, k_slab=2)
 
 # -- op level: fwd + VJP (incl. the psum'd dfeats) vs the einsum ref --------
 rng = np.random.default_rng(0)
@@ -228,8 +228,8 @@ g = make_sbm_graph(n=202, n_classes=4, avg_degree=8, feat_dim=16, seed=5)
 base = GNNConfig(name="md", model="gcn", n_nodes=g.n, feat_dim=16,
                  hidden=16, n_classes=g.n_classes, n_layers=2,
                  fanout=(4, 3), batch_size=30, loss="ce")
-kcfg = dataclasses.replace(base, use_agg_kernel=True, agg_interpret=True,
-                           agg_b_tile=4, agg_d_tile=8, agg_k_slab=2)
+kcfg = dataclasses.replace(base, use_agg_kernel=True, agg_b_tile=4,
+                           agg_d_tile=8, agg_k_slab=2)
 plan = TrainPlan(lr=0.3, n_iters=4, eval_every=2, seed=0)
 for make in (lambda: ShardedFullGraphSource(),
              lambda: ShardedSampledSource(batch_size=30)):

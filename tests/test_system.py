@@ -124,3 +124,32 @@ def test_serve_chain_end_to_end():
     out = jnp.concatenate(toks, 1)
     assert out.shape == (2, 4)
     assert int(cache["pos"]) == 36
+
+
+def test_compile_cache_dir_from_env_is_left_alone(monkeypatch, tmp_path):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and JAX's config is untouched
+    (JAX reads the variable itself)."""
+    from repro.launch import cache
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a: calls.append(a))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert cache.enable_compile_cache() == str(tmp_path)
+    assert calls == []
+
+
+def test_compile_cache_default_is_a_fixed_path_in_the_checkout(
+        monkeypatch):
+    """Without the variable the cache goes to ``<repo>/.jax_cache``, the
+    same path on every run (the config update is captured, so this test
+    turns no cache on)."""
+    import os
+    from repro.launch import cache
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a: calls.append(a))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(repo, ".jax_cache")
+    assert cache.enable_compile_cache() == want
+    assert calls == [("jax_compilation_cache_dir", want)]
