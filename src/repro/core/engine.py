@@ -68,6 +68,7 @@ import numpy as np
 
 from repro.configs.base import GNNConfig
 from repro.core import gnn as G
+from repro.core import tracing
 from repro.core.graph import Graph, to_ell
 from repro.core.metrics import History
 from repro.core.prefetch import HostStagingRing, Prefetcher
@@ -101,6 +102,7 @@ def _device_ell(graph: Graph, max_deg: Optional[int] = None):
     over distinct ``max_deg`` values no longer accretes one full
     [n, K] upload per grid point (sources that need a capped ELL to
     outlive the cache hold their own reference via ``self.ell``).
+    Each key also keeps its count of kept edges (``_ell_edges``).
     """
     key = _resolve_max_deg(graph, max_deg)
     cache = getattr(graph, "_ell_cache", None)
@@ -114,8 +116,30 @@ def _device_ell(graph: Graph, max_deg: Optional[int] = None):
         for stale in [k for k in cache if k != "base"]:
             del cache[stale]
         idx, w, w_self = to_ell(graph, max_deg=max_deg)
-        cache[key] = (jnp.asarray(idx), jnp.asarray(w), jnp.asarray(w_self))
-    return cache[key] + cache["base"]
+        cache[key] = ((jnp.asarray(idx), jnp.asarray(w),
+                       jnp.asarray(w_self)), int(np.count_nonzero(w)))
+    return cache[key][0] + cache["base"]
+
+
+def _ell_edges(graph: Graph, max_deg: Optional[int]) -> int:
+    """Kept edges (nonzero weights) of the ELL ``_device_ell`` cached
+    for this cap."""
+    return graph._ell_cache[_resolve_max_deg(graph, max_deg)][1]
+
+
+def _count_ell(cfg: GNNConfig, rows: int, k: int, edges: int,
+               shards: int = 1) -> None:
+    """Record a bound ELL's ``ell_slots`` (the (row, slot) entries one
+    aggregation call reads) and ``ell_edges`` (those holding a kept
+    edge).  The tiled kernel pads each shard's rows to ``agg_b_tile``
+    and K to ``agg_k_slab``, and DMAs every padded slot; the einsum path
+    reads ``[rows, K]``."""
+    if cfg.use_agg_kernel and cfg.model in ("gcn", "graphsage"):
+        per_shard = -(-rows // shards)
+        rows = shards * (-(-per_shard // cfg.agg_b_tile) * cfg.agg_b_tile)
+        k = -(-k // cfg.agg_k_slab) * cfg.agg_k_slab
+    tracing.count("ell_slots", rows * k)
+    tracing.count("ell_edges", edges)
 
 
 def _device_nodes(graph: Graph, which: str):
@@ -506,7 +530,9 @@ class FullGraphSource(BatchSource):
 
     def bind(self, graph, cfg, plan):
         self.graph, self.cfg = graph, cfg
-        self.ell = _device_ell(graph, self._cap(cfg))
+        cap = self._cap(cfg)
+        self.ell = _device_ell(graph, cap)
+        _count_ell(cfg, *self.ell[0].shape, _ell_edges(graph, cap))
         self.train_nodes = _device_nodes(graph, "train")
         self.n_nodes = len(graph.train_nodes)
         return self
@@ -594,8 +620,9 @@ class ShardedFullGraphSource(FullGraphSource):
                    jax.device_put(np.ascontiguousarray(w_self), rows1),
                    jax.device_put(np.ascontiguousarray(feats), rows2),
                    jax.device_put(np.ascontiguousarray(labels), rows1))
-            cache[key] = (ell, repl, {})
-        self.ell, self._repl, self._splits = cache[key]
+            cache[key] = (ell, repl, {}, int(np.count_nonzero(w)))
+        self.ell, self._repl, self._splits, edges = cache[key]
+        _count_ell(cfg, *self.ell[0].shape, edges, shards=n_dev)
         self.feats_plan = None
         self.featshard_stats = None
         if cfg.feats_layout == "sharded" and cfg.use_agg_kernel:
@@ -810,7 +837,8 @@ class SampledSource(BatchSource):
                  + [(s.shape, s.dtype) for s in fb.self_w]
                  + [(fb.labels.shape, fb.labels.dtype)]
                  + [(v.shape, v.dtype) for v in extra])
-        slot = self._ring.acquire()
+        with tracing.span("ring_wait"):
+            slot = self._ring.acquire()
         try:
             bufs = iter(self._ring.buffers(slot, specs))
             feats = []
@@ -875,7 +903,9 @@ class SampledSource(BatchSource):
     def batches(self):
         # resume-aware: a restored stream starts at batch `_consumed`
         # with the rng fast-forwarded to the checkpointed state, so the
-        # sequence continues bit-for-bit where the checkpoint left off
+        # sequence continues bit-for-bit where the checkpoint left off.
+        # A batch's id in the tracing spans is its index in the stream,
+        # the iteration that consumes it.
         remaining = self.n_iters - self._consumed
         if self.prefetch:
             self._pf = Prefetcher(self.graph, self.b_request, self.fanouts,
@@ -883,13 +913,16 @@ class SampledSource(BatchSource):
                                   n_batches=remaining,
                                   payload_fn=self._host_batch,
                                   sample_fn=self._sample,
-                                  rng_state=self._resume_rng_state)
+                                  rng_state=self._resume_rng_state,
+                                  first_batch=self._consumed)
             try:
                 for _ in range(remaining):
                     fb, payload = self._pf.next()
                     self._last_rng_state = self._pf.last_rng_state
+                    with tracing.span("device_put", self._consumed):
+                        batch = self._to_device(payload)
                     self._consumed += 1
-                    yield self._to_device(payload), fb.batch_size
+                    yield batch, fb.batch_size
             finally:
                 self.close()
         else:
@@ -897,12 +930,17 @@ class SampledSource(BatchSource):
             if self._resume_rng_state is not None:
                 rng.bit_generator.state = self._resume_rng_state
             for _ in range(remaining):
-                fb = self._sample(rng, self.graph, self.b_request,
-                                  self.fanouts)
+                i = self._consumed
+                with tracing.span("sample", i):
+                    fb = self._sample(rng, self.graph, self.b_request,
+                                      self.fanouts)
                 self._last_rng_state = rng.bit_generator.state
+                with tracing.span("stage", i):
+                    payload = self._host_batch(self.graph, fb)
+                with tracing.span("device_put", i):
+                    batch = self._to_device(payload)
                 self._consumed += 1
-                yield self._to_device(self._host_batch(self.graph, fb)), \
-                    fb.batch_size
+                yield batch, fb.batch_size
 
     def done(self, batch) -> None:
         if self._ring is not None and self._inflight:
@@ -1295,13 +1333,16 @@ class ClusterSource(BatchSource):
                               depth=2, n_batches=remaining,
                               payload_fn=lambda g, batch: None,
                               sample_fn=self._sample_union,
-                              rng_state=self._resume_rng_state)
+                              rng_state=self._resume_rng_state,
+                              first_batch=self._consumed)
         try:
             for _ in range(remaining):
                 (host, n_valid), _ = self._pf.next()
                 self._last_rng_state = self._pf.last_rng_state
+                with tracing.span("device_put", self._consumed):
+                    batch = jax.device_put(host)
                 self._consumed += 1
-                yield jax.device_put(host), n_valid
+                yield batch, n_valid
         finally:
             self.close()
 
@@ -1498,6 +1539,7 @@ class Trainer:
                  callbacks: Optional[Sequence[Callback]] = None,
                  extra_callbacks: Sequence[Callback] = ()):
         self.graph, self.cfg, self.plan = graph, cfg, plan
+        tracing.clear()                  # the log holds this run alone
         self.source = (source or SampledSource()).bind(graph, cfg, plan)
         self.callbacks = (list(callbacks) if callbacks is not None
                           else default_callbacks(plan))
@@ -1747,12 +1789,16 @@ class Trainer:
                     state.params, state.opt_state = params, opt_state
                     state.rollback_pending = False
                 if state.stop:
+                    # the last batch drawn when the stop came (one past
+                    # the stopping record under deferred sync)
+                    tracing.note_stop(it)
                     break
             if pending is not None:
                 # drain the lagged record so History stays aligned with
                 # the params actually returned
                 self._consume(pending, state)
             if state.stop:
+                tracing.note_stop(state.it)
                 self._fire("on_stop", state)
             acc = self.evaluate(params, self.source.node_split("test"))
             state.params = params

@@ -40,7 +40,7 @@ from typing import List, Optional, Sequence, Tuple, Type
 
 import numpy as np
 
-from repro.core import faults
+from repro.core import faults, tracing
 from repro.core.graph import Graph
 from repro.core.sampler import FanoutBatch, gather_features, sample_batch
 
@@ -126,7 +126,10 @@ class Prefetcher:
     ``backoff * 2**attempt``, capped at ``backoff_cap`` seconds);
     `transient` is the tuple of exception types classified transient.
     `rng_state` (a ``numpy`` bit-generator state dict, as exposed by
-    `last_rng_state`) resumes the batch stream mid-sequence.
+    `last_rng_state`) resumes the batch stream mid-sequence, and
+    `first_batch` is the index of its first batch there: the batch id of
+    the ``sample`` / ``stage`` spans (worker) and the ``queue_wait`` span
+    (``next()``) that ``core.tracing`` records.
     """
 
     _SENTINEL = object()
@@ -139,7 +142,8 @@ class Prefetcher:
                  backoff: float = 0.05, backoff_cap: float = 2.0,
                  transient: Tuple[Type[BaseException], ...]
                  = DEFAULT_TRANSIENT,
-                 rng_state: Optional[dict] = None):
+                 rng_state: Optional[dict] = None,
+                 first_batch: int = 0):
         self.graph = graph
         self.batch_size = batch_size
         self.fanouts = tuple(fanouts)
@@ -162,9 +166,12 @@ class Prefetcher:
         #: completed transient restarts so far
         self.restarts = 0
         self._produced = 0               # survives worker restarts
+        self._delivered = 0              # batches handed out by next()
+        self._first_batch = first_batch
         self._finished = False           # end-of-stream sentinel consumed
         self._pre_draw_state: Optional[dict] = None
-        self._thread = threading.Thread(target=self._worker, daemon=True)
+        self._thread = threading.Thread(target=self._worker,
+                                        name="prefetch", daemon=True)
         self._thread.start()
 
     # ------------------------------------------------------------------
@@ -177,9 +184,12 @@ class Prefetcher:
             # sample/payload rewinds here, so the restarted worker
             # replays this very batch and ordering is preserved
             self._pre_draw_state = self._rng.bit_generator.state
-            fb = self.sample_fn(self._rng, self.graph,
-                                self.batch_size, self.fanouts)
-            payload = self.payload_fn(self.graph, fb)
+            batch = self._first_batch + self._produced
+            with tracing.span("sample", batch):
+                fb = self.sample_fn(self._rng, self.graph,
+                                    self.batch_size, self.fanouts)
+            with tracing.span("stage", batch):
+                payload = self.payload_fn(self.graph, fb)
             post_state = self._rng.bit_generator.state
             # blocking put with timeout so close() can interrupt
             while not self._stop.is_set():
@@ -212,7 +222,8 @@ class Prefetcher:
                     return
                 if self._pre_draw_state is not None:
                     self._rng.bit_generator.state = self._pre_draw_state
-                t = threading.Thread(target=self._worker, daemon=True)
+                t = threading.Thread(target=self._worker, name="prefetch",
+                                     daemon=True)
                 self._thread = t
                 t.start()
                 return                           # old thread retires
@@ -243,7 +254,9 @@ class Prefetcher:
             if self._err is not None:
                 raise self._err
             raise StopIteration
-        item = self._q.get()
+        with tracing.span("queue_wait",
+                          self._first_batch + self._delivered):
+            item = self._q.get()
         if item is self._SENTINEL:
             self._finished = True
             if self._err is not None:
@@ -251,6 +264,7 @@ class Prefetcher:
             raise StopIteration
         fb, payload, post_state = item
         self.last_rng_state = post_state
+        self._delivered += 1
         return fb, payload
 
     def __iter__(self):
