@@ -424,9 +424,9 @@ def phase_train_sampled(graph, cfg, iters, seed, clock):
     ref_src.bind(graph, cfg, plan)
     stream = ref_src.batches()
     (b0, _), (b1, _) = next(stream), next(stream)
-    rcfg = ref_cfg(cfg)
+    rcfg, consts = ref_cfg(cfg), ref_src.loss_consts()
     refs = two_step_losses(
-        lambda p, b: SampledSource._loss_impl(p, b, (), rcfg), plan,
+        lambda p, b: SampledSource._loss_impl(p, b, consts, rcfg), plan,
         init_params(cfg, plan), b0, b1)
     ref_src.close()
     rels = loss_check("train_sampled", losses, refs)

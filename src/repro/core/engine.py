@@ -72,8 +72,7 @@ from repro.core import tracing
 from repro.core.graph import Graph, to_ell
 from repro.core.metrics import History
 from repro.core.prefetch import HostStagingRing, Prefetcher
-from repro.core.sampler import (FanoutBatch, expand_batch, gather_features,
-                                sample_batch)
+from repro.core.sampler import FanoutBatch, expand_batch, sample_batch
 
 
 # ---------------------------------------------------------------------------
@@ -92,33 +91,44 @@ def _resolve_max_deg(graph: Graph, max_deg: Optional[int]) -> int:
     return int(max_deg)
 
 
+def _device_base(graph: Graph):
+    """``(feats, labels)`` on the device, uploaded once per graph as the
+    ELL cache's max_deg-independent ``"base"`` entry: evaluation, the
+    full-graph ELL and the sampled step's in-step row gather share this
+    one copy of the feature table."""
+    cache = getattr(graph, "_ell_cache", None)
+    if cache is None:
+        cache = {}
+        object.__setattr__(graph, "_ell_cache", cache)
+    if "base" not in cache:
+        cache["base"] = (jnp.asarray(graph.feats),
+                         jnp.asarray(graph.labels))
+    return cache["base"]
+
+
 def _device_ell(graph: Graph, max_deg: Optional[int] = None):
     """Device-resident ELL layout, memoized per graph: evaluation and the
     full-loss tracker used to rebuild (re-pad + re-upload) it on every
     call.  The cache lives on the Graph instance so it dies with it.
 
     At most ONE ELL key is resident besides the max_deg-independent
-    "base" uploads: inserting a new key evicts the others, so a sweep
-    over distinct ``max_deg`` values no longer accretes one full
-    [n, K] upload per grid point (sources that need a capped ELL to
-    outlive the cache hold their own reference via ``self.ell``).
-    Each key also keeps its count of kept edges (``_ell_edges``).
+    "base" uploads (``_device_base``): inserting a new key evicts the
+    others, so a sweep over distinct ``max_deg`` values no longer
+    accretes one full [n, K] upload per grid point (sources that need a
+    capped ELL to outlive the cache hold their own reference via
+    ``self.ell``).  Each key also keeps its count of kept edges
+    (``_ell_edges``).
     """
     key = _resolve_max_deg(graph, max_deg)
-    cache = getattr(graph, "_ell_cache", None)
-    if cache is None:
-        cache = {}
-        object.__setattr__(graph, "_ell_cache", cache)
-    if "base" not in cache:                  # max_deg-independent uploads
-        cache["base"] = (jnp.asarray(graph.feats),
-                         jnp.asarray(graph.labels))
+    base = _device_base(graph)
+    cache = graph._ell_cache
     if key not in cache:
         for stale in [k for k in cache if k != "base"]:
             del cache[stale]
         idx, w, w_self = to_ell(graph, max_deg=max_deg)
         cache[key] = ((jnp.asarray(idx), jnp.asarray(w),
                        jnp.asarray(w_self)), int(np.count_nonzero(w)))
-    return cache[key][0] + cache["base"]
+    return cache[key][0] + base
 
 
 def _ell_edges(graph: Graph, max_deg: Optional[int]) -> int:
@@ -140,6 +150,14 @@ def _count_ell(cfg: GNNConfig, rows: int, k: int, edges: int,
         k = -(-k // cfg.agg_k_slab) * cfg.agg_k_slab
     tracing.count("ell_slots", rows * k)
     tracing.count("ell_edges", edges)
+
+
+def _gather_hops(feats, hop_ids):
+    """Each hop's feature rows ``[b, f1..fd, r]``, gathered inside the
+    compiled step from the device table by the hop's node ids
+    ``[b, f1..fd]``.  Staging range-checks the ids, so ``clip`` moves
+    none of them."""
+    return [jnp.take(feats, ids, axis=0, mode="clip") for ids in hop_ids]
 
 
 def _device_nodes(graph: Graph, which: str):
@@ -698,14 +716,21 @@ class SampledSource(BatchSource):
     trees from the vectorized CSR sampler, optionally produced ahead of
     the device step by a background ``Prefetcher`` thread.
 
+    A batch carries each hop's node ids, not its feature rows: the
+    compiled step gathers the rows from the device-resident feature
+    table (``_device_base``, the copy evaluation already holds), which
+    it takes as an argument.  Staging range-checks the ids (a device
+    gather would clip an out-of-range id silently) and counts them as
+    ``device_gather_rows``.
+
     Device uploads go through a ``HostStagingRing``: host staging buffers
     are allocated ONCE per shape and recycled across batches (the ring
     slot is released in ``done`` once the consuming step has synced; with
     the engine's deferred loss sync that release lags one extra step, so
-    the ring grows by one slot).  Hop features are gathered DIRECTLY into
-    the slot's buffers (``np.take(..., out=)``) and masks cast bool->f32
-    in place, so the plain path's fresh per-batch allocations disappear;
-    with ``prefetch`` that staging work runs on the Prefetcher's worker
+    the ring grows by one slot).  Ids (cast to int32), masks (bool->f32),
+    weights and labels are copied straight into the slot's buffers, so
+    the plain path's fresh per-batch allocations disappear; with
+    ``prefetch`` that staging work runs on the Prefetcher's worker
     thread, off the device step's critical path.  The whole batch then
     ships as a single ``jax.device_put`` pytree transfer instead of
     ~4·n_layers separate ``jnp.asarray`` uploads.
@@ -764,19 +789,25 @@ class SampledSource(BatchSource):
             # loss sync)
             extra = 1 if _deferred_mode(plan) else 0
             self._ring = HostStagingRing(self.depth + 2 + extra)
+        self.feats = _device_base(graph)[0]
         return self
 
     @staticmethod
     def _loss_impl(params, batch, consts, cfg: GNNConfig):
+        (feats,) = consts
         if len(batch) == 6:              # padded batch: masked mean
-            feats, masks, weights, self_w, labels, valid = batch
+            ids, masks, weights, self_w, labels, valid = batch
         else:
-            feats, masks, weights, self_w, labels = batch
+            ids, masks, weights, self_w, labels = batch
             valid = None
-        logits = G.minibatch_forward(params, cfg, feats, masks, weights,
-                                     self_w)
+        logits = G.minibatch_forward(params, cfg, _gather_hops(feats, ids),
+                                     masks, weights, self_w)
         return G.gnn_loss(logits, labels, cfg.loss, cfg.n_classes,
                           valid=valid)
+
+    def loss_consts(self):
+        # the device feature table the step gathers hop rows from
+        return (self.feats,)
 
     def loss(self, params, batch):
         return type(self)._loss_impl(params, batch, self.loss_consts(),
@@ -824,57 +855,49 @@ class SampledSource(BatchSource):
         valid_n = fb.batch_size
         fb = self._pad_batch(fb)
         extra: Tuple = tuple(self._extra_cols(fb, valid_n))
+        for ids in fb.nodes:
+            # the step's gather clips an out-of-range id to a real row,
+            # so it has to fail here, as a host gather of the rows did
+            if ids.size and (ids.min() < 0 or ids.max() >= graph.n):
+                raise IndexError(
+                    f"sampled node ids outside [0, {graph.n}): "
+                    f"{ids.min()}..{ids.max()}")
+        # the lists (ids, masks, weights, self_w) and the single arrays
+        # (labels, extra columns), each with the dtype it ships in
+        lists = [(fb.nodes, np.int32), (fb.masks, np.float32),
+                 (fb.weights, None), (fb.self_w, None)]
+        singles = [fb.labels, *extra]
         if self._ring is None:
-            feats = gather_features(graph, fb)
-            masks = [m.astype(np.float32) for m in fb.masks]
-            return -1, (feats, masks, fb.weights, fb.self_w,
-                        fb.labels) + extra
-        fd = graph.feats.shape[1]
-        specs = ([(ids.shape + (fd,), graph.feats.dtype)
-                  for ids in fb.nodes]
-                 + [(m.shape, np.float32) for m in fb.masks]
-                 + [(w.shape, w.dtype) for w in fb.weights]
-                 + [(s.shape, s.dtype) for s in fb.self_w]
-                 + [(fb.labels.shape, fb.labels.dtype)]
-                 + [(v.shape, v.dtype) for v in extra])
-        with tracing.span("ring_wait"):
-            slot = self._ring.acquire()
-        try:
-            bufs = iter(self._ring.buffers(slot, specs))
-            feats = []
-            for ids in fb.nodes:      # gather straight into the buffer
-                buf = next(bufs)
-                np.take(graph.feats, ids.reshape(-1), axis=0,
-                        out=buf.reshape(-1, fd))
-                feats.append(buf)
-            masks = []
-            for m in fb.masks:        # in-place bool -> f32 cast
-                buf = next(bufs)
-                np.copyto(buf, m, casting="unsafe")
-                masks.append(buf)
-            small = []
-            for arrs in (fb.weights, fb.self_w):
-                out = []
-                for a in arrs:
+            host = (tuple([a.astype(dt or a.dtype, copy=False)
+                           for a in arrs] for arrs, dt in lists)
+                    + tuple(singles))
+            slot = -1
+        else:
+            specs = ([(a.shape, dt or a.dtype)
+                      for arrs, dt in lists for a in arrs]
+                     + [(a.shape, a.dtype) for a in singles])
+            with tracing.span("ring_wait"):
+                slot = self._ring.acquire()
+            try:
+                bufs = iter(self._ring.buffers(slot, specs))
+
+                def fill(a):          # copy (and cast) into the slot
                     buf = next(bufs)
-                    np.copyto(buf, a)
-                    out.append(buf)
-                small.append(out)
-            labels = next(bufs)
-            np.copyto(labels, fb.labels)
-            tail = []
-            for v in extra:
-                buf = next(bufs)
-                np.copyto(buf, v)
-                tail.append(buf)
-        except BaseException:
-            # a worker dying mid-batch must not strand its staging slot:
-            # the consuming step never runs, so done() would never
-            # release it and the ring would leak one slot per failure
-            self._ring.release(slot)
-            raise
-        return slot, (feats, masks, small[0], small[1], labels) \
-            + tuple(tail)
+                    np.copyto(buf, a, casting="same_kind")
+                    return buf
+
+                host = (tuple([fill(a) for a in arrs] for arrs, _ in lists)
+                        + tuple(fill(a) for a in singles))
+            except BaseException:
+                # a worker dying mid-batch must not strand its staging
+                # slot: the consuming step never runs, so done() would
+                # never release it and the ring would leak one slot per
+                # failure
+                self._ring.release(slot)
+                raise
+        tracing.count("device_gather_rows",
+                      sum(ids.size for ids in fb.nodes))
+        return slot, host
 
     def _to_device(self, payload):
         """One device_put for the whole batch; the ring slot joins an
@@ -1073,9 +1096,10 @@ class ImportanceSampledSource(SampledSource):
 
     @staticmethod
     def _loss_impl(params, batch, consts, cfg: GNNConfig):
-        feats, masks, weights, self_w, labels, valid, row_w = batch
-        logits = G.minibatch_forward(params, cfg, feats, masks, weights,
-                                     self_w)
+        (feats,) = consts
+        ids, masks, weights, self_w, labels, valid, row_w = batch
+        logits = G.minibatch_forward(params, cfg, _gather_hops(feats, ids),
+                                     masks, weights, self_w)
         return G.gnn_loss(logits, labels, cfg.loss, cfg.n_classes,
                           valid=valid, weight=row_w)
 
@@ -1088,7 +1112,9 @@ class ShardedSampledSource(SampledSource):
     ``HostStagingRing``); only the upload differs: every leaf of the
     batch pytree is ``device_put`` with a NODES-sharded leading axis
     (``sharding.row_sharding``), so XLA GSPMD partitions the fan-out
-    tree forward per device shard and all-reduces the gradients.
+    tree forward per device shard and all-reduces the gradients.  The
+    feature table the step gathers hop rows from is replicated over the
+    mesh, so each device gathers the rows of its own ids.
 
     ``b`` is rounded UP to a multiple of the mesh size; the surplus
     rows ride the engine's existing masked-row padding (the valid
@@ -1119,6 +1145,7 @@ class ShardedSampledSource(SampledSource):
         self.pad = max(0, self.b - min(self.b_request,
                                        len(graph.train_nodes)))
         self._repl = sh.named((None,), mesh)
+        self.feats = jax.device_put(self.feats, self._repl)
         self._row_shardings: dict = {}
         self._repl_splits: dict = {}
         # feats_layout="sharded": sampled fan-outs change every step, so
@@ -1145,20 +1172,23 @@ class ShardedSampledSource(SampledSource):
 
     @staticmethod
     def _loss_impl(params, batch, consts, cfg: GNNConfig):
-        (mesh,) = consts
+        feats, mesh = consts
         if len(batch) == 6:              # padded batch: masked mean
-            feats, masks, weights, self_w, labels, valid = batch
+            ids, masks, weights, self_w, labels, valid = batch
         else:
-            feats, masks, weights, self_w, labels = batch
+            ids, masks, weights, self_w, labels = batch
             valid = None
-        logits = G.minibatch_forward(params, cfg, feats, masks, weights,
-                                     self_w, mesh=mesh)
+        # a replicated table gathered by row-sharded ids: each device
+        # gathers its own rows, no collective
+        logits = G.minibatch_forward(params, cfg, _gather_hops(feats, ids),
+                                     masks, weights, self_w, mesh=mesh)
         return G.gnn_loss(logits, labels, cfg.loss, cfg.n_classes,
                           valid=valid)
 
     def loss_consts(self):
-        # the static mesh for the shard_map'd kernel path
-        return (self._mesh,)
+        # the replicated table, and the static mesh for the shard_map'd
+        # kernel path
+        return (self.feats, self._mesh)
 
     def _row_sharding(self, ndim: int):
         from repro import sharding as sh

@@ -21,7 +21,9 @@ Span names: ``sample``, ``stage`` and its child ``ring_wait``,
 ``queue_wait`` and ``device_put`` (``core/prefetch.py``,
 ``core/engine.py``).  Counters: ``ell_slots`` and ``ell_edges``, the
 ELL entries one aggregation call reads and those that hold an edge
-(``FullGraphSource.bind``).
+(``FullGraphSource.bind``); ``device_gather_rows``, the node ids of
+every staged sampled batch, whose feature rows the step gathers on the
+device (``SampledSource._host_batch``).
 """
 from __future__ import annotations
 
