@@ -76,21 +76,22 @@ def numbers(prog: dict, ref: dict) -> dict:
 
 def reference_run(conf: dict, traffic: dict, graph: dict, seed: int,
                   nodes=None, ell=None, lowp=None, half_batch=False,
-                  precision="highest") -> dict:
+                  precision="highest", model=None) -> dict:
     """Three reference steps from ``seed``: ``losses``, ``g0``, ``p0``,
     ``p3``, and for sampled cells ``sampler_faults``.  ``nodes`` holds
     the three batches' node ids per hop (sampled); ``ell`` the capped
-    adjacency ``(idx, kept)`` (full graph)."""
+    adjacency ``(idx, kept)`` (full graph); ``model`` the model's module
+    (``Registry.model``), by default the one the configuration names."""
     gnn, plan = conf["gnn"], conf["plan"]
-    p0 = R.init_params(gnn, seed)
+    p0 = R.init_params(gnn, seed, model)
     out = {"p0": p0}
     if traffic["source"] == "FullGraphSource":
         data = R.fullgraph_plan(graph, *ell, gnn["n_layers"])
-        init, step = R.fullgraph_step(gnn, plan, lowp, half_batch)
+        init, step = R.fullgraph_step(gnn, plan, lowp, half_batch, model)
         args = (jnp.asarray(graph["feats"]), jax.device_put(data))
         batches = [args] * 3
     elif traffic["source"] == "SampledSource":
-        init, step = R.sampled_step(gnn, plan, lowp, half_batch)
+        init, step = R.sampled_step(gnn, plan, lowp, half_batch, model)
         faults, batches = 0, []
         fanouts = traffic["args"]["fanouts"]
         for ids in nodes:

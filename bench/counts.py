@@ -1,5 +1,6 @@
-"""Operations and bytes of a training step, from shapes, keyed by model
-kind.  They count the work the algorithm needs, not what an
+"""Operations and bytes of a training step, from shapes; each model's
+module (``bench/models``) gives its layers' aggregation calls and dense
+FLOPs.  They count the work the algorithm needs, not what an
 implementation moves: a later PR that changes the tiling, pads less or
 skips padded slots is judged against the same numbers.
 
@@ -21,7 +22,7 @@ from __future__ import annotations
 import json
 from typing import Dict, Sequence
 
-from bench.reference import layer_dims
+from bench import models
 
 
 def agg_call(out_rows: int, edges: int, d: int, itemsize: int,
@@ -35,48 +36,45 @@ def agg_call(out_rows: int, edges: int, d: int, itemsize: int,
     return {"flops": float(flops), "bytes": float(nbytes)}
 
 
-def _check_model(gnn: dict) -> None:
-    if gnn["model"] not in ("graphsage", "gcn"):
-        raise ValueError(f"no counts for model {gnn['model']!r}")
-
-
-def fullgraph(gnn: dict, n: int, edges: int) -> Dict[str, object]:
+def fullgraph(gnn: dict, n: int, edges: int, model=None) -> Dict[str, object]:
     """Counts of one full-graph step over ``n`` nodes whose capped ELL
     holds ``edges`` real edges.  Aggregation tables are in the
-    configuration's ``dtype``; a layer that narrows transforms first and
-    aggregates its output width."""
-    _check_model(gnn)
+    configuration's ``dtype``; the model says whether a layer transforms
+    first.  ``model`` is the model's module, by default the one
+    ``gnn["model"]`` names."""
+    m = model or models.load(gnn["model"])
     item = 2 if gnn["dtype"] == "bfloat16" else 4
+    dims = m.layer_dims(gnn)
     calls, fwd = [], 0.0
-    for d_in, d_out in layer_dims(gnn):
-        d_src = d_out if d_out < d_in else d_in
-        call = agg_call(n, edges, d_src, item, gnn["model"] == "gcn")
-        calls.append(call)
-        dense = 2.0 * n * d_in * d_out
-        fwd += call["flops"] + (2 * dense if gnn["model"] == "graphsage"
-                                else dense)
+    for li, (d_in, d_out) in enumerate(dims):
+        lc, dense = m.layer_counts(gnn, n, edges, d_in, d_out, item,
+                                   m.transforms_first(d_in, d_out),
+                                   li == len(dims) - 1)
+        calls.extend(lc)
+        fwd += sum(c["flops"] for c in lc) + dense
     return {"agg_calls": calls, "model_flops": 3.0 * fwd}
 
 
 def sampled(gnn: dict, batch: int, fanouts: Sequence[int],
-            edges: Sequence[float]) -> Dict[str, object]:
+            edges: Sequence[float], model=None) -> Dict[str, object]:
     """Counts of one sampled step: ``batch`` targets, ``edges[d]`` real
     slots from hop ``d`` to hop ``d + 1`` (averaged over the batches).
-    Hop rows are float32; layer ``l`` aggregates hops ``d < L - l``."""
-    _check_model(gnn)
+    Hop rows are float32 and arrive untransformed; layer ``l`` aggregates
+    hops ``d < L - l``.  ``model`` as in ``fullgraph``."""
+    m = model or models.load(gnn["model"])
     n_layers = gnn["n_layers"]
     rows = [batch]
     for f in fanouts:
         rows.append(rows[-1] * f)
+    dims = m.layer_dims(gnn)
     calls, fwd = [], 0.0
-    for li, (d_in, d_out) in enumerate(layer_dims(gnn)):
+    for li, (d_in, d_out) in enumerate(dims):
         for d in range(n_layers - li):
-            call = agg_call(rows[d], int(round(edges[d])), d_in, 4,
-                            gnn["model"] == "gcn")
-            calls.append(call)
-            dense = 2.0 * rows[d] * d_in * d_out
-            fwd += call["flops"] + (2 * dense if gnn["model"] == "graphsage"
-                                    else dense)
+            lc, dense = m.layer_counts(gnn, rows[d], int(round(edges[d])),
+                                       d_in, d_out, 4, False,
+                                       li == len(dims) - 1)
+            calls.extend(lc)
+            fwd += sum(c["flops"] for c in lc) + dense
     return {"agg_calls": calls, "model_flops": 3.0 * fwd}
 
 

@@ -15,7 +15,7 @@ without the span gives ``None``: the metric stays out of the line.
 """
 from __future__ import annotations
 
-from typing import List, Optional
+from typing import Dict, List, Optional
 
 
 def snapshot() -> Optional[dict]:
@@ -47,6 +47,23 @@ def mean_span_ms(record: dict, name: str) -> Optional[float]:
     ids = window_batches(record, snap)
     if ids is None:
         return None
+    return _mean_self_ms(snap, ids, name)
+
+
+def span_means(steps: int, names) -> Dict[str, Optional[float]]:
+    """``mean_span_ms`` of each of ``names`` over a window of ``steps``
+    steps, with no trace to ask: for a run's ``info`` line, never a
+    metric.  Empty where the program keeps no log."""
+    snap = snapshot()
+    if snap is None:
+        return {}
+    ids = window_batches({"window": {"steps": steps}}, snap)
+    if ids is None:
+        return {}
+    return {name: _mean_self_ms(snap, ids, name) for name in names}
+
+
+def _mean_self_ms(snap: dict, ids: List[int], name: str) -> Optional[float]:
     own = {i: 0 for i in ids}
     seen = set()
     for s in snap["spans"]:

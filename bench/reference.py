@@ -5,8 +5,10 @@ adjacency, the mini-batch tensors, the forwards, the loss and the
 optimizer are written out again from their published descriptions (the
 repository's ``core/gnn.py``, ``core/graph.py``, ``core/sampler.py`` and
 ``optim/optimizers.py`` implement the same equations), and every matrix
-product runs at ``highest`` precision.  A run compares what its timed
-path produced with these, in ``bench/check.py``.
+product runs at ``highest`` precision.  What belongs to one model (its
+weights, its layer, whether it transforms before the gather) is in its
+module under ``bench/models``; the drivers here name no model.  A run
+compares what its timed path produced with these, in ``bench/check.py``.
 
 ``lowp`` (a dtype or None) rounds every aggregation table and both
 operands of every matrix product to that dtype: the reference computed
@@ -16,12 +18,12 @@ takes the mean over the rest: a planted fault.
 """
 from __future__ import annotations
 
-import math
-
 import jax
 import jax.numpy as jnp
 import ml_dtypes
 import numpy as np
+
+from bench import models
 
 F32 = jnp.float32
 #: output rows per chunk of the full-graph reference
@@ -41,25 +43,17 @@ def layer_dims(gnn: dict):
     return dims
 
 
-def init_params(gnn: dict, seed: int):
-    """Normal(0, 1/d_in) weights from ``jax.random.key(seed)``, one key
-    folded in per layer: ``w`` for GCN, ``w_self`` and ``w_neigh`` from
-    the layer key's split for GraphSAGE."""
+def init_params(gnn: dict, seed: int, model=None):
+    """Each layer's weights from ``jax.random.key(seed)`` with the layer's
+    index folded in, by the model's ``init_layer``.  ``model`` is the
+    model's module (``bench/models``), by default the one ``gnn["model"]``
+    names."""
+    m = model or models.load(gnn["model"])
     key = jax.random.key(seed)
-    params = []
-    for li, (d_in, d_out) in enumerate(layer_dims(gnn)):
-        k = jax.random.fold_in(key, li)
-        sc = 1.0 / math.sqrt(d_in)
-        if gnn["model"] == "gcn":
-            params.append({"w": sc * jax.random.normal(k, (d_in, d_out), F32)})
-        elif gnn["model"] == "graphsage":
-            k1, k2 = jax.random.split(k)
-            params.append(
-                {"w_self": sc * jax.random.normal(k1, (d_in, d_out), F32),
-                 "w_neigh": sc * jax.random.normal(k2, (d_in, d_out), F32)})
-        else:
-            raise ValueError(f"no reference for model {gnn['model']!r}")
-    return params
+    dims = m.layer_dims(gnn)
+    return [m.init_layer(jax.random.fold_in(key, li), d_in, d_out,
+                         li == len(dims) - 1, gnn)
+            for li, (d_in, d_out) in enumerate(dims)]
 
 
 def adam(plan: dict):
@@ -112,29 +106,13 @@ def _mm(a, b, lowp):
     return _round(a, lowp) @ _round(b, lowp)
 
 
-def _layer(model, p, last, self_rows, nb_rows, w, mask, w_self, pre,
+def _layer(m, p, last, self_rows, nb_rows, w, mask, w_self, pre,
            lowp=None):
-    """One layer for a block of output rows from its gathered rows.
-
-    GraphSAGE-mean: ``self @ w_self + mean_k(nb) @ w_neigh``; where the
-    layer narrows (``pre``) the neighbour rows are already ``h @ w_neigh``.
-    GCN: ``(sum_k w_k nb_k + w_self self) @ w``, the rows already
-    ``h @ w`` where ``pre``.  ReLU on every layer but the last."""
-    if model == "graphsage":
-        cnt = jnp.maximum(mask.sum(-1, keepdims=True), 1.0)
-        mean = jnp.einsum("...k,...kd->...d", mask, nb_rows) / cnt
-        out = (_mm(self_rows, p["w_self"], lowp)
-               + (mean if pre else _mm(mean, p["w_neigh"], lowp)))
-    else:
-        agg = (jnp.einsum("...k,...kd->...d", w, nb_rows)
-               + w_self[..., None] * self_rows)
-        out = agg if pre else _mm(agg, p["w"], lowp)
+    """One layer of model module ``m`` for a block of output rows from its
+    gathered rows (where ``pre``, already transformed); ReLU on every
+    layer but the last."""
+    out = m.layer(p, last, self_rows, nb_rows, w, mask, w_self, pre, lowp)
     return out if last else jax.nn.relu(out)
-
-
-def _pre(model, p):
-    wn = p["w_neigh"] if model == "graphsage" else p["w"]
-    return wn, wn.shape[1] < wn.shape[0]
 
 
 # ---------------------------------------------------------------------------
@@ -202,14 +180,15 @@ def fullgraph_plan(g, ell_idx, kept, n_layers):
                 valid=np.pad(np.ones(tr.size, np.float32), (0, pad)))
 
 
-def fullgraph_step(gnn, plan, lowp=None, half_batch=False):
+def fullgraph_step(gnn, plan, lowp=None, half_batch=False, model=None):
     """Jitted ``(params, opt_state, feats, data) -> (loss, grads as the
     optimizer gets them, params, opt_state)``: one full-graph training
     step of the reference, layer by layer in chunks of ``CHUNK`` rows.
     The backward runs chunk by chunk too: each chunk's VJP over its own
     gathered rows, scatter-added into the layer's input table, so no
-    [rows, K, d] gather is ever whole."""
-    model = gnn["model"]
+    [rows, K, d] gather is ever whole.  ``model`` as in ``init_params``."""
+    m = model or models.load(gnn["model"])
+    dims = m.layer_dims(gnn)
     init, update = adam(plan)
 
     def chunks(x):
@@ -220,13 +199,13 @@ def fullgraph_step(gnn, plan, lowp=None, half_batch=False):
 
         def layer_fn(l, p, table):
             last = l == n_layers - 1
-            wn, pre = _pre(model, p)
-            src = _round(_mm(table, wn, lowp) if pre else table, lowp)
-            selft = src if model == "gcn" else _round(table, lowp)
+            pre = m.transforms_first(*dims[l])
+            src = _round(m.transform(p, table, lowp) if pre else table, lowp)
+            selft = src if m.SELF_FROM_SOURCE else _round(table, lowp)
             d = data["layers"][l]
 
             def fn(p, self_rows, nb_rows, w, mask, w_self):
-                return _layer(model, p, last, self_rows, nb_rows, w, mask,
+                return _layer(m, p, last, self_rows, nb_rows, w, mask,
                               w_self, pre, lowp)
             return src, selft, d, fn
 
@@ -251,12 +230,12 @@ def fullgraph_step(gnn, plan, lowp=None, half_batch=False):
             grads = [None] * n_layers
             for l in reversed(range(n_layers)):
                 p, table = params[l], tables[l]
-                wn, pre = _pre(model, p)
+                pre = m.transforms_first(*dims[l])
                 d = data["layers"][l]
                 src, src_vjp = jax.vjp(
-                    lambda p, t: _round(_mm(t, _pre(model, p)[0], lowp)
+                    lambda p, t: _round(m.transform(p, t, lowp)
                                         if pre else t, lowp), p, table)
-                selft = src if model == "gcn" else _round(table, lowp)
+                selft = src if m.SELF_FROM_SOURCE else _round(table, lowp)
                 _, _, _, fn = layer_fn(l, p, table)
                 gc = chunks(g)
                 xs = (chunks(d["self"]), chunks(d["nb"]), chunks(d["w"]),
@@ -285,7 +264,7 @@ def fullgraph_step(gnn, plan, lowp=None, half_batch=False):
                     0, gc.shape[0], body,
                     (jax.tree.map(jnp.zeros_like, p), jnp.zeros_like(selft),
                      jnp.zeros_like(src)))
-                if model == "gcn":
+                if m.SELF_FROM_SOURCE:
                     dsrc = dsrc + dself
                     dself = jnp.zeros_like(table)
                 gp, gt = src_vjp(dsrc)
@@ -365,18 +344,19 @@ def _find(indices, lo, hi, child):
     return np.where(lo < end, lo, end)
 
 
-def sampled_step(gnn, plan, lowp=None, half_batch=False):
+def sampled_step(gnn, plan, lowp=None, half_batch=False, model=None):
     """Jitted ``(params, opt_state, tensors) -> (loss, grads as the
     optimizer gets them, params, opt_state)`` on one sampled batch: each
-    layer aggregates hop ``d + 1`` into hop ``d``."""
-    model = gnn["model"]
+    layer aggregates hop ``d + 1`` into hop ``d``.  ``model`` as in
+    ``init_params``."""
+    m = model or models.load(gnn["model"])
     init, update = adam(plan)
 
     def loss_fn(params, t):
         hs = [_round(f, lowp) for f in t["feats"]]
         for l, p in enumerate(params):
             last = l == len(params) - 1
-            hs = [_layer(model, p, last, hs[d], _round(hs[d + 1], lowp),
+            hs = [_layer(m, p, last, hs[d], _round(hs[d + 1], lowp),
                          t["weights"][d], t["masks"][d], t["self_w"][d],
                          False, lowp)
                   for d in range(len(hs) - 1)]

@@ -1,4 +1,5 @@
 """Finds what ``BENCHMARK.json`` names: configurations by their ``file``,
+the module of a configuration's model in ``bench/models/<model>.py``,
 traffic mixes in ``bench/traffic/<traffic>.json``, per-layer metric
 readers in ``bench/metrics/<metric>.py`` and a cell's output limits in
 ``bench/limits/<workload>.json``.  A new one is a new file and an entry
@@ -8,6 +9,8 @@ from __future__ import annotations
 import importlib.util
 import json
 import os
+
+from bench import models
 
 
 class Registry:
@@ -33,6 +36,11 @@ class Registry:
     def config(self, name: str) -> dict:
         with open(os.path.join(self.root, self._entry("configs", name)["file"])) as f:
             return json.load(f)
+
+    def model(self, name: str):
+        """The module of model ``name`` (``bench/models``), which the
+        reference and the counts ask for what is the model's own."""
+        return models.load(name, self.root)
 
     def traffic(self, name: str) -> dict:
         return self._json("traffic", name + ".json")

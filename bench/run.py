@@ -52,9 +52,15 @@ import numpy as np  # noqa: E402
 from bench import check  # noqa: E402
 from bench import counts  # noqa: E402
 from bench import graph as bgraph  # noqa: E402
+from bench import program_spans  # noqa: E402
 from bench import reference as R  # noqa: E402
 from bench import trace as btrace  # noqa: E402
 from bench.registry import Registry  # noqa: E402
+
+
+#: the program's spans whose means over the window's batches every run
+#: prints on an ``info`` line (``bench/program_spans.py``)
+PROGRAM_SPANS = ("sample", "stage", "queue_wait", "device_put")
 
 
 def parse(argv):
@@ -279,6 +285,7 @@ class Cell:
         self.spec = self.reg.workload(workload)
         self.conf = self.reg.config(self.spec["config"])
         self.traffic = self.reg.traffic(self.spec["traffic"])
+        self.model = self.reg.model(self.conf["gnn"]["model"])
         self.devs = require_chip(self.spec["chips"])
         self.cache_dir = os.path.join(root, "bench", ".cache")
         use_compile_cache(os.path.join(self.cache_dir, "jax"))
@@ -351,15 +358,16 @@ class Cell:
         return check.reference_run(self.conf, self.traffic, self.arrays,
                                    seed, nodes=nodes, ell=ell, lowp=lowp,
                                    half_batch=half_batch,
-                                   precision=precision)
+                                   precision=precision, model=self.model)
 
     def step_counts(self, probe: "Probe") -> dict:
         if self.traffic["source"] == "FullGraphSource":
-            return counts.fullgraph(self.conf["gnn"], self.n, self.kept_edges)
+            return counts.fullgraph(self.conf["gnn"], self.n,
+                                    self.kept_edges, self.model)
         args = self.traffic["args"]
         return counts.sampled(self.conf["gnn"], args["batch_size"],
                               args["fanouts"],
-                              np.mean(probe.slot_counts, axis=0))
+                              np.mean(probe.slot_counts, axis=0), self.model)
 
 
 def main(argv=None, root: str = ROOT) -> int:
@@ -378,6 +386,8 @@ def main(argv=None, root: str = ROOT) -> int:
          window_steps=win["steps"], window_s=win["seconds"],
          step_median_max_s=([float(np.median(gaps)), float(gaps.max())]
                             if gaps.size else None),
+         step_p90_s=float(np.percentile(gaps, 90)) if gaps.size else None,
+         span_ms=program_spans.span_means(win["steps"], PROGRAM_SPANS),
          bad_steps=probe.bad, memory_peak_bytes=probe.memory_peak,
          ell_kept_edge_share=cell.kept_edges / max(cell.edges, 1))
 

@@ -36,13 +36,15 @@ def tiny_conf(name, model, n_layers, **gnn):
 
 def make_root(path, limits=None):
     """A checkout-shaped directory: ``BENCHMARK.json`` with tiny cells on
-    the repository's per-layer readers and peaks (plus a ``cpu`` row so a
+    the repository's model modules, per-layer readers and peaks (plus a ``cpu`` row so a
     traced CPU run can reduce), and limits for every cell."""
     bench = os.path.join(path, "bench")
     for d in ("configs", "traffic", "limits"):
         os.makedirs(os.path.join(bench, d), exist_ok=True)
-    shutil.copytree(os.path.join(ROOT, "bench", "metrics"),
-                    os.path.join(bench, "metrics"), dirs_exist_ok=True)
+    for d in ("metrics", "models"):
+        shutil.copytree(os.path.join(ROOT, "bench", d), os.path.join(bench, d),
+                        dirs_exist_ok=True,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     with open(os.path.join(ROOT, "bench", "peaks.json")) as f:
         peaks = json.load(f)
     peaks["cpu"] = dict(next(iter(peaks.values())), source="test")

@@ -19,6 +19,7 @@ def test_every_entry_resolves():
         conf = reg.config(c["name"])
         assert conf["gnn"]["name"] == c["name"]
         assert set(c["reduced"]) == set(conf["reduced"])
+        assert callable(reg.model(conf["gnn"]["model"]).layer)
     compared = {"loss0", "loss1", "loss2", "grad0", "grad0_dist", "change3"}
     for w in reg.spec["workloads"]:
         traffic = reg.traffic(w["traffic"])
