@@ -141,8 +141,9 @@ class GNNConfig:
     loss: str = "ce"                     # ce | mse
     # --- Pallas neighbor-aggregation kernel (kernels/neighbor_agg) ---
     # Routes the Ã-weighted aggregation of gcn/graphsage through the
-    # batch-tiled software-gather kernel in BOTH forward paths.  GAT keeps
-    # the einsum path (per-edge softmax attention is not a weighted sum).
+    # batch-tiled software-gather kernel in BOTH forward paths, and GAT's
+    # full-graph attention sum (one call per head, the softmax weights
+    # as edge weights); GAT's mini-batch sum stays an einsum.
     # The kernel compiles on a TPU backend and runs in the Pallas
     # interpreter on any other (repro.kernels.default_interpret).
     use_agg_kernel: bool = False
@@ -193,6 +194,9 @@ class GNNConfig:
         if self.model == "gat":
             req(self.gat_heads > 0,
                 f"gat_heads must be > 0, got {self.gat_heads}")
+            req(self.hidden % self.gat_heads == 0,
+                f"hidden ({self.hidden}) must split evenly over "
+                f"gat_heads ({self.gat_heads})")
         for f in ("agg_b_tile", "agg_d_tile", "agg_k_slab"):
             req(getattr(self, f) > 0,
                 f"{f} must be > 0, got {getattr(self, f)}")

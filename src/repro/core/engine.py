@@ -142,14 +142,32 @@ def _count_ell(cfg: GNNConfig, rows: int, k: int, edges: int,
     """Record a bound ELL's ``ell_slots`` (the (row, slot) entries one
     aggregation call reads) and ``ell_edges`` (those holding a kept
     edge).  The tiled kernel pads each shard's rows to ``agg_b_tile``
-    and K to ``agg_k_slab``, and DMAs every padded slot; the einsum path
-    reads ``[rows, K]``."""
-    if cfg.use_agg_kernel and cfg.model in ("gcn", "graphsage"):
+    and K to ``agg_k_slab``, and DMAs every padded slot (GAT's per-head
+    calls each read the same slots); the einsum path reads
+    ``[rows, K]``."""
+    if cfg.use_agg_kernel:
         per_shard = -(-rows // shards)
         rows = shards * (-(-per_shard // cfg.agg_b_tile) * cfg.agg_b_tile)
         k = -(-k // cfg.agg_k_slab) * cfg.agg_k_slab
     tracing.count("ell_slots", rows * k)
     tracing.count("ell_edges", edges)
+
+
+def _count_attn(cfg: GNNConfig, feat_dim: int) -> None:
+    """Record GAT's ``attn_cols`` (the real columns ``H·dh`` summed over
+    one forward's attention-aggregation calls) and ``attn_kernel_cols``
+    (the columns those calls read: the tiled kernel pads each head's
+    block as ``ops.kernel_cols`` says; the einsum path reads them as
+    they are).  Other models record neither."""
+    if cfg.model != "gat":
+        return
+    from repro.kernels.neighbor_agg.ops import kernel_cols
+    agg_dt = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
+    for heads, dh in G.gat_head_dims(cfg, feat_dim):
+        tracing.count("attn_cols", heads * dh)
+        tracing.count("attn_kernel_cols", heads * (
+            kernel_cols(dh, agg_dt, cfg.agg_d_tile) if cfg.use_agg_kernel
+            else dh))
 
 
 def _gather_hops(feats, hop_ids):
@@ -551,6 +569,7 @@ class FullGraphSource(BatchSource):
         cap = self._cap(cfg)
         self.ell = _device_ell(graph, cap)
         _count_ell(cfg, *self.ell[0].shape, _ell_edges(graph, cap))
+        _count_attn(cfg, graph.feats.shape[1])
         self.train_nodes = _device_nodes(graph, "train")
         self.n_nodes = len(graph.train_nodes)
         return self
@@ -641,6 +660,7 @@ class ShardedFullGraphSource(FullGraphSource):
             cache[key] = (ell, repl, {}, int(np.count_nonzero(w)))
         self.ell, self._repl, self._splits, edges = cache[key]
         _count_ell(cfg, *self.ell[0].shape, edges, shards=n_dev)
+        _count_attn(cfg, graph.feats.shape[1])
         self.feats_plan = None
         self.featshard_stats = None
         if cfg.feats_layout == "sharded" and cfg.use_agg_kernel:

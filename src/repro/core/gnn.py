@@ -29,8 +29,19 @@ def layer_dims(cfg: GNNConfig, feat_dim: int) -> List[tuple]:
     return dims
 
 
+def gat_head_dims(cfg: GNNConfig, feat_dim: int) -> List[tuple]:
+    """GAT's ``(heads, dh)`` per layer: hidden layers split their width
+    over the heads and concatenate them; the last layer emits full class
+    logits per head and averages them."""
+    dims = layer_dims(cfg, feat_dim)
+    return [(cfg.gat_heads, d_out if li == len(dims) - 1
+             else max(d_out // cfg.gat_heads, 1))
+            for li, (_, d_out) in enumerate(dims)]
+
+
 def init_gnn(key, cfg: GNNConfig, feat_dim: int) -> List[Dict[str, Any]]:
     params = []
+    heads = gat_head_dims(cfg, feat_dim) if cfg.model == "gat" else None
     for li, (d_in, d_out) in enumerate(layer_dims(cfg, feat_dim)):
         k = jax.random.fold_in(key, li)
         sc = 1.0 / math.sqrt(d_in)
@@ -41,11 +52,7 @@ def init_gnn(key, cfg: GNNConfig, feat_dim: int) -> List[Dict[str, Any]]:
             p = {"w_self": sc * jax.random.normal(k1, (d_in, d_out), F32),
                  "w_neigh": sc * jax.random.normal(k2, (d_in, d_out), F32)}
         else:  # gat
-            h = cfg.gat_heads
-            last = li == cfg.n_layers - 1
-            # hidden layers concat heads (dh = d_out/h); the last layer
-            # emits full class logits per head and averages them.
-            dh = d_out if last else max(d_out // h, 1)
+            h, dh = heads[li]
             k1, k2, k3 = jax.random.split(k, 3)
             p = {"w": sc * jax.random.normal(k1, (d_in, h, dh), F32),
                  "a_src": 0.1 * jax.random.normal(k2, (h, dh), F32),
@@ -127,21 +134,94 @@ def _sage_layer(cfg, p, h_self, h_nb, mask, mesh=None):
     return h_self @ p["w_self"] + mean @ p["w_neigh"]
 
 
+def _gat_attention(s_t, s_nb, s_self, mask):
+    """GAT's attention weights over a row's K neighbour slots plus its
+    self edge, in float32: ``e = LeakyReLU_0.2(s_t[i] + s_n[j])`` with
+    ``s_t = z·a_src`` the target's score and ``s_n = z·a_dst`` the
+    neighbour's, masked slots left out, softmax over the rest and the
+    self edge (always valid).  ``s_t`` and ``s_self`` (the target's own
+    ``s_n``) are ``[..., rows]``, ``s_nb`` ``[..., rows, K]`` and
+    ``mask`` broadcasts to it: the slots on the minor axis, and the heads
+    leading where the caller has them, for a TPU pads a minor axis of 3
+    heads to 128 lanes.  -> ``(alpha [..., rows, K], alpha_self
+    [..., rows])``."""
+    with jax.named_scope("gat_attention"):
+        s_t, s_nb, s_self = (x.astype(F32) for x in (s_t, s_nb, s_self))
+        e = jax.nn.leaky_relu(s_t[..., None] + s_nb, 0.2)
+        e = jnp.where(mask > 0, e, -1e30)
+        e_self = jax.nn.leaky_relu(s_t + s_self, 0.2)
+        top = jnp.maximum(e.max(-1), e_self)
+        ex = jnp.exp(e - top[..., None])               # masked slots: 0
+        ex_self = jnp.exp(e_self - top)
+        denom = ex.sum(-1) + ex_self
+        return ex / denom[..., None], ex_self / denom
+
+
+def gat_transform(p, h):
+    """``z = h @ W`` once per row, the heads side by side:
+    ``[..., d_in] -> [..., H·dh]``."""
+    w = p["w"]
+    return h @ w.reshape(w.shape[0], -1)
+
+
 def _gat_layer(p, h_self, h_nb, mask):
-    z_s = jnp.einsum("...d,dhe->...he", h_self, p["w"])        # [..., H, dh]
-    z_n = jnp.einsum("...kd,dhe->...khe", h_nb, p["w"])        # [..., K, H, dh]
-    e_s = jnp.einsum("...he,he->...h", z_s, p["a_src"])        # [..., H]
-    e_n = jnp.einsum("...khe,he->...kh", z_n, p["a_dst"])      # [..., K, H]
-    e = jax.nn.leaky_relu(e_s[..., None, :] + e_n, 0.2)
-    e = jnp.where(mask[..., None], e, -1e30)
-    # self edge always valid
-    e_self = jax.nn.leaky_relu(e_s + jnp.einsum("...he,he->...h", z_s,
-                                                p["a_dst"]))[..., None, :]
-    ea = jnp.concatenate([e, e_self], axis=-2)                 # [...,K+1,H]
-    alpha = jax.nn.softmax(ea, axis=-2)
-    zn_all = jnp.concatenate([z_n, z_s[..., None, :, :]], axis=-3)
-    out = jnp.einsum("...kh,...khe->...he", alpha, zn_all)
+    """The mini-batch GAT layer on a fan-out level: every slot holds its
+    own row of the tree, so ``W`` applies per slot and the weighted sum
+    stays an einsum.  Heads concatenated."""
+    heads, dh = p["a_src"].shape
+    z_s = gat_transform(p, h_self).reshape(h_self.shape[:-1] + (heads, dh))
+    z_n = gat_transform(p, h_nb).reshape(h_nb.shape[:-1] + (heads, dh))
+
+    def score(z, a):                                       # heads leading
+        return jnp.moveaxis(jnp.einsum("...he,he->...h", z, a), -1, 0)
+    alpha, alpha_self = _gat_attention(
+        score(z_s, p["a_src"]), score(z_n, p["a_dst"]),
+        score(z_s, p["a_dst"]), mask)
+    out = (jnp.einsum("h...k,...khe->...he", alpha, z_n)
+           + jnp.moveaxis(alpha_self, 0, -1)[..., None] * z_s)
     return out.reshape(out.shape[:-2] + (-1,))                 # concat heads
+
+
+def gat_ell_layer(cfg: GNNConfig, p, z, table, idx, mask, last: bool,
+                  rows=None, mesh=None):
+    """One GAT layer's attention aggregation over ELL rows, heads joined:
+    ``out[i, h] = Σ_k α[h,i,k]·z[idx[i,k], h] + α_self[h,i]·z[i, h]``,
+    concatenated, or averaged on the last layer.
+
+    ``z`` ``[n, H·dh]`` is the layer's transform of every node
+    (``gat_transform``) and ``table`` the same rows in the aggregation
+    dtype, the gather source; ``idx``/``mask`` are the ELL ids and real
+    slots of the output rows, which are ``rows`` (global ids) or, where
+    None, all n.  Head by head: the scores are per node, the edge logits
+    an ``[rows, K]`` gather of them, and with ``cfg.use_agg_kernel`` the
+    weighted sum is one call of the tiled kernel on the head's column
+    block, ``α`` the edge weights and the self edge the fused self term
+    (the kernel's VJP gives ``∂α`` as its weight gradient), so no
+    ``[rows, K, d]`` array exists; otherwise an einsum over the gathered
+    rows.  Each head's sum is rounded to ``table``'s dtype, as the kernel
+    writes it."""
+    heads, dh = p["a_src"].shape
+    nb = None if cfg.use_agg_kernel else jnp.take(table, idx, axis=0)
+    self_tab = table if rows is None else jnp.take(table, rows, axis=0)
+    outs = []
+    for hd in range(heads):
+        c = slice(hd * dh, (hd + 1) * dh)
+        s_t, s_n = z[:, c] @ p["a_src"][hd], z[:, c] @ p["a_dst"][hd]
+        s_self = s_n
+        if rows is not None:
+            s_t, s_self = jnp.take(s_t, rows), jnp.take(s_n, rows)
+        alpha, alpha_self = _gat_attention(s_t, jnp.take(s_n, idx), s_self,
+                                           mask)
+        if cfg.use_agg_kernel:
+            out = _kernel_agg(cfg, table[:, c], idx, alpha,
+                              self_rows=self_tab[:, c], w_self=alpha_self,
+                              mesh=mesh)
+        else:
+            out = (jnp.einsum("nk,nkd->nd", alpha, nb[..., c])
+                   + alpha_self[:, None] * self_tab[:, c]
+                   ).astype(table.dtype)
+        outs.append(out.astype(z.dtype))
+    return sum(outs) / heads if last else jnp.concatenate(outs, axis=1)
 
 
 def _apply_layer(cfg: GNNConfig, p, h_self, h_nb, mask, w_edge, w_self,
@@ -153,8 +233,7 @@ def _apply_layer(cfg: GNNConfig, p, h_self, h_nb, mask, w_edge, w_self,
     else:
         out = _gat_layer(p, h_self, h_nb, mask)
         if last:  # average heads into class logits
-            h = cfg.gat_heads
-            out = out.reshape(out.shape[:-1] + (h, -1)).mean(-2)
+            out = out.reshape(out.shape[:-1] + (cfg.gat_heads, -1)).mean(-2)
     return out if last else jax.nn.relu(out)
 
 
@@ -182,7 +261,8 @@ def full_graph_forward(params, cfg: GNNConfig, feats, ell_idx, ell_w,
     the batch-tiled Pallas software-gather kernel on the replicated
     source table — no [n, K, d] gather is materialized (the kernel DMAs
     rows tile-by-tile and keeps the (b_tile, d_tile) accumulator in
-    VMEM).  GAT keeps the einsum path (per-edge softmax attention).
+    VMEM).  GAT transforms once per node and runs its attention-weighted
+    sum through the same kernel, one call per head (``gat_ell_layer``).
 
     ``mesh`` (sharded sources) partitions the KERNEL path over the
     NODES mesh axis via shard_map — ELL rows shard, the source table
@@ -197,9 +277,8 @@ def full_graph_forward(params, cfg: GNNConfig, feats, ell_idx, ell_w,
     degree-ordered hot cache splitting the gather into shard-local hits
     and one compacted cold-miss all_gather.  Every layer's output table
     stays NODES-sharded, so it feeds the next layer (and the layer-wise
-    inference pass) without a relayout.  GAT ignores the plan (its
-    attention gather is not a weighted sum; engine binds never build a
-    plan for it).
+    inference pass) without a relayout.  GAT ignores the plan: its
+    per-head calls gather from the replicated table.
 
     ``return_layers`` additionally returns every layer's POST-activation
     table ``[h_1, ..., h_L]`` (``h_L`` = the logits) — the per-layer
@@ -286,12 +365,10 @@ def full_graph_forward(params, cfg: GNNConfig, feats, ell_idx, ell_w,
             cnt = jnp.maximum(mask.sum(-1, keepdims=True), 1.0)
             mean = agg_w(replicate(src), mask_agg) / cnt
             out = h @ p["w_self"] + (mean if pre else mean @ wn)
-        else:  # gat — gathers the (usually narrower) projected z already
-            nb = jnp.take(replicate(h), ell_idx, axis=0).astype(h.dtype)
-            out = _gat_layer(p, h, nb, maskb)
-            if last:
-                heads = cfg.gat_heads
-                out = out.reshape(out.shape[:-1] + (heads, -1)).mean(-2)
+        else:  # gat — transform once per node, gather the projected z
+            z = gat_transform(p, h)
+            out = gat_ell_layer(cfg, p, z, replicate(z), ell_idx, maskb,
+                                last, mesh=mesh)
         h = out if last else jax.nn.relu(out)
         if return_layers:
             layers.append(h)

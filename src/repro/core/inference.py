@@ -57,11 +57,17 @@ def _matmul(h, wmat):
     return h @ wmat
 
 
+_gat_transform = jax.jit(G.gat_transform)
+
+
 def _pre_source(cfg: GNNConfig, p, h):
     """The full forward's width-shrinking trick, once per LAYER (not per
     chunk): when a layer narrows (d_out < d_in) the linear transform
     runs before aggregation (Ã(hW) == (Ãh)W), so every chunk gathers
-    d_out-wide rows.  GAT gathers raw ``h`` (per-edge attention)."""
+    d_out-wide rows.  GAT's source is its per-node transform ``z``
+    ``[n, H·dh]``, which every chunk gathers."""
+    if cfg.model == "gat":
+        return _gat_transform(p, h)
     wmat = p.get("w") if cfg.model == "gcn" else p.get("w_neigh")
     if wmat is not None and wmat.shape[1] < h.shape[1]:
         return _matmul(h, wmat)
@@ -120,13 +126,9 @@ def _chunk_apply(cfg: GNNConfig, last: bool, mesh, p, h, src, rows, idx,
         mean = agg_w(src, mask_agg) / cnt
         out = jnp.take(h, rows, axis=0) @ p["w_self"] \
             + (mean if pre else mean @ wn)
-    else:  # gat — per-edge softmax attention stays on the einsum path
-        h_rows = jnp.take(h, rows, axis=0)
-        nb = jnp.take(h.astype(agg_dt), idx, axis=0).astype(h.dtype)
-        out = G._gat_layer(p, h_rows, nb, maskb)
-        if last:
-            heads = cfg.gat_heads
-            out = out.reshape(out.shape[:-1] + (heads, -1)).mean(-2)
+    else:  # gat — src is z, the layer's transform of every node
+        out = G.gat_ell_layer(cfg, p, src, src.astype(agg_dt), idx, maskb,
+                              last, rows=rows, mesh=mesh)
     return out if last else jax.nn.relu(out)
 
 
@@ -288,8 +290,8 @@ def _featshard_run(params, scfg: GNNConfig, feats, ell,
         raise ValueError(
             "featshard inference needs use_agg_kernel=True and a "
             f"gcn/graphsage model, got model={scfg.model!r}, "
-            f"use_agg_kernel={scfg.use_agg_kernel} (GAT's attention "
-            "gather is not a weighted sum — use the chunked path)")
+            f"use_agg_kernel={scfg.use_agg_kernel} (GAT runs on the "
+            "replicated table — use the chunked path)")
     idx, w, w_self = ell
     n = int(feats.shape[0])
     pad = fsplan.n_pad - n

@@ -21,9 +21,16 @@ Span names: ``sample``, ``stage`` and its child ``ring_wait``,
 ``queue_wait`` and ``device_put`` (``core/prefetch.py``,
 ``core/engine.py``).  Counters: ``ell_slots`` and ``ell_edges``, the
 ELL entries one aggregation call reads and those that hold an edge
-(``FullGraphSource.bind``); ``device_gather_rows``, the node ids of
-every staged sampled batch, whose feature rows the step gathers on the
-device (``SampledSource._host_batch``).
+(``FullGraphSource.bind``); ``attn_cols`` and ``attn_kernel_cols``,
+GAT's real columns summed over one forward's attention-aggregation calls
+and the columns those calls read as the kernel pads each head (the same
+bind); ``device_gather_rows``, the node ids of every staged sampled
+batch, whose feature rows the step gathers on the device
+(``SampledSource._host_batch``).
+
+GAT's edge logits and softmax run under ``jax.named_scope
+("gat_attention")`` (``core/gnn.py``), which names their device
+operations in a profile.
 """
 from __future__ import annotations
 

@@ -34,20 +34,26 @@ from repro.kernels.neighbor_agg.neighbor_agg import (
 from repro.kernels.neighbor_agg.ref import neighbor_agg_ref
 
 
-def _kernel_width(feats, static) -> int:
-    """Columns the kernel's D pads to.  The tiled kernel gathers rows
-    of 32-bit words (an f32 column or two bf16 columns per word): a row
-    of at most half a lane tile pads to exactly half a tile, which the
-    kernel pairs two rows per tile; a wider row pads to whole tiles."""
-    kernel, d_tile = static[:2]
-    d = feats.shape[1]
-    if kernel == "row":
-        return -(-d // d_tile) * d_tile
-    lanes = lanes_per_row(feats.dtype)
+def kernel_cols(d: int, dtype, d_tile: int) -> int:
+    """Columns the tiled kernel's D pads to.  It gathers rows of 32-bit
+    words (an f32 column or two bf16 columns per word): a row of at most
+    half a lane tile pads to exactly half a tile, which the kernel pairs
+    two rows per tile; a wider row pads to whole tiles."""
+    lanes = lanes_per_row(dtype)
     words = -(-d // lanes)
     if d_tile % 2 == 0 and 2 * words <= d_tile:
         return d_tile // 2 * lanes
     return -(-words // d_tile) * d_tile * lanes
+
+
+def _kernel_width(feats, static) -> int:
+    """Columns the kernel's D pads to (``kernel_cols`` for the tiled
+    kernel, whole ``d_tile`` blocks for the row kernel)."""
+    kernel, d_tile = static[:2]
+    d = feats.shape[1]
+    if kernel == "row":
+        return -(-d // d_tile) * d_tile
+    return kernel_cols(d, feats.dtype, d_tile)
 
 
 def _pad_cols(x, width):

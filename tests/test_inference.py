@@ -59,7 +59,7 @@ def _assert_layers_close(got, want, **tol):
 @pytest.mark.parametrize("model,kernel", [
     ("gcn", False), ("gcn", True),
     ("graphsage", False), ("graphsage", True),
-    ("gat", False),
+    ("gat", False), ("gat", True),
 ])
 # 37 does not divide n=300, 150 does, 999 > n collapses to one chunk
 @pytest.mark.parametrize("chunk", [37, 150, 999])

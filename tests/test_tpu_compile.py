@@ -185,3 +185,19 @@ def test_fullgraph_step_compiles_at_papers_width(topo, compiled_kernels):
                           gnn_steps.fullgraph_input_specs)
     assert _has_kernel(compiled)
     _fits(compiled)
+
+
+def test_gat_fullgraph_step_compiles_at_arxiv_width(topo, compiled_kernels):
+    """GAT at ogbn-arxiv's published widths (3 layers, 3 heads × 250, the
+    last averaging 3 heads of 40 classes) over all 169,343 nodes, ELL
+    K = 32: one kernel call per head and layer, and the step fits."""
+    from repro.configs.base import GNNConfig
+    from repro.launch import gnn_steps
+    cfg = GNNConfig(name="gat-arxiv", model="gat", n_nodes=169343,
+                    feat_dim=128, hidden=750, gat_heads=3, n_classes=40,
+                    n_layers=3, fanout=(15, 10, 5), max_degree=32,
+                    dtype="bfloat16", use_agg_kernel=True)
+    compiled = _step_args(topo, cfg, gnn_steps.make_fullgraph_step,
+                          gnn_steps.fullgraph_input_specs)
+    assert compiled.as_text().count("tpu_custom_call") == 9
+    _fits(compiled)

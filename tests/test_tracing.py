@@ -296,3 +296,33 @@ def test_pad_counter_matches_hand_count(src_cls, kernel):
     cache = g._sharded_ell_cache if src_cls is ShardedFullGraphSource \
         else g._ell_cache
     assert len([key for key in cache if key != "base"]) == 1
+
+
+@pytest.mark.parametrize("src_cls", [FullGraphSource,
+                                     ShardedFullGraphSource],
+                         ids=["plain", "sharded"])
+@pytest.mark.parametrize("kernel", [True, False], ids=["kernel", "einsum"])
+def test_gat_counters_match_hand_count(src_cls, kernel):
+    """GAT's ELL slots pad as GCN's do, and its attention columns: 3
+    heads of 6 on the hidden layer, of 3 classes on the last; with f32
+    tiles of 8 lanes the kernel reads 8 columns a head on the hidden
+    layer (a whole tile) and 4 on the last (half a tile, paired)."""
+    g = _tiny_csr()
+    cfg = _cfg(g, model="gat", hidden=18, gat_heads=3, max_degree=3,
+               use_agg_kernel=kernel, agg_b_tile=8, agg_d_tile=8,
+               agg_k_slab=4)
+    Trainer(g, cfg, TrainPlan(lr=0.1, n_iters=1), source=src_cls())
+    c = tracing.snapshot()["counters"]
+    assert c["attn_cols"] == 3 * 6 + 3 * 3
+    assert c["attn_kernel_cols"] == (3 * 8 + 3 * 4 if kernel else 27)
+    want = (_hand_share(g, 3, 16, 4) if kernel
+            else _hand_share(g, 3, 13, 3))
+    assert 1.0 - c["ell_edges"] / c["ell_slots"] == pytest.approx(
+        want, rel=1e-12)
+
+
+def test_other_models_record_no_attention_counters(graph):
+    Trainer(graph, _cfg(graph, model="gcn", max_degree=4),
+            TrainPlan(lr=0.1, n_iters=1), source=FullGraphSource())
+    c = tracing.snapshot()["counters"]
+    assert "ell_slots" in c and "attn_cols" not in c
