@@ -209,6 +209,11 @@ def _eval_acc(params, cfg: GNNConfig, idx, w, w_self, feats, labels,
     return G.accuracy(logits[nodes], labels[nodes])
 
 
+#: layer 0's aggregation of the fixed feature table (``gnn.layer0_agg``),
+#: computed once per full-graph bind
+_layer0_agg = jax.jit(G.layer0_agg, static_argnums=0)
+
+
 def _split_consts(consts) -> Tuple[Tuple, Tuple, TCallable]:
     """Split a source's ``loss_consts()`` into the arrays a compiled
     function takes as ARGUMENTS and the static rest (meshes; a
@@ -550,7 +555,13 @@ class BatchSource:
 class FullGraphSource(BatchSource):
     """The (b=n_train, beta=d_max) limit: every iteration is GD over ALL
     training nodes on the device-resident ELL layout; the "batch" is
-    empty because everything is constant across iterations."""
+    empty because everything is constant across iterations.
+
+    Where layer 0 gathers the raw feature table before it transforms it
+    (GCN and GraphSAGE whose first layer does not narrow), that
+    aggregation is the same in every step: ``bind`` computes it once
+    (``gnn.layer0_agg``, ``[n, feat_dim]`` in the aggregation dtype) and
+    holds it on the device as ``agg0``, an argument of the step."""
 
     loss_is_full_loss = True
     name = "fullgraph"
@@ -560,6 +571,7 @@ class FullGraphSource(BatchSource):
         # None = every neighbour).  A (b, beta) sweep passes its beta.
         self.max_deg = max_deg
         self.ell = None
+        self.agg0 = None
 
     def _cap(self, cfg: GNNConfig) -> Optional[int]:
         return cfg.max_degree if self.max_deg is None else self.max_deg
@@ -570,20 +582,24 @@ class FullGraphSource(BatchSource):
         self.ell = _device_ell(graph, cap)
         _count_ell(cfg, *self.ell[0].shape, _ell_edges(graph, cap))
         _count_attn(cfg, graph.feats.shape[1])
+        idx, w, w_self, feats, _ = self.ell
+        self.agg0 = _layer0_agg(_static_cfg(cfg), feats, idx, w, w_self)
+        tracing.count("hoisted_agg_layers", int(self.agg0 is not None))
         self.train_nodes = _device_nodes(graph, "train")
         self.n_nodes = len(graph.train_nodes)
         return self
 
     @staticmethod
     def _loss_impl(params, batch, consts, cfg: GNNConfig):
-        idx, w, w_self, feats, labels, train_nodes = consts
-        logits = G.full_graph_forward(params, cfg, feats, idx, w, w_self)
+        idx, w, w_self, feats, labels, train_nodes, agg0 = consts
+        logits = G.full_graph_forward(params, cfg, feats, idx, w, w_self,
+                                      agg0=agg0)
         lt = logits[train_nodes]
         return G.gnn_loss(lt, labels[train_nodes], cfg.loss,
                           cfg.n_classes)
 
     def loss_consts(self):
-        return tuple(self.ell) + (self.train_nodes,)
+        return tuple(self.ell) + (self.train_nodes, self.agg0)
 
     def loss(self, params, batch):
         return type(self)._loss_impl(params, batch, self.loss_consts(),
@@ -597,6 +613,7 @@ class FullGraphSource(BatchSource):
         # idempotent: drop the device ELL reference (the per-graph cache
         # keeps at most one resident key; sources release theirs here)
         self.ell = None
+        self.agg0 = None
 
 
 class ShardedFullGraphSource(FullGraphSource):
@@ -661,6 +678,7 @@ class ShardedFullGraphSource(FullGraphSource):
         self.ell, self._repl, self._splits, edges = cache[key]
         _count_ell(cfg, *self.ell[0].shape, edges, shards=n_dev)
         _count_attn(cfg, graph.feats.shape[1])
+        tracing.count("hoisted_agg_layers", 0)   # every layer in the step
         self.feats_plan = None
         self.featshard_stats = None
         if cfg.feats_layout == "sharded" and cfg.use_agg_kernel:

@@ -241,9 +241,99 @@ def _apply_layer(cfg: GNNConfig, p, h_self, h_nb, mask, w_edge, w_self,
 # full-graph forward (ELL)
 # ---------------------------------------------------------------------------
 
+def _ell_aggregation(cfg: GNNConfig, ell_idx, ell_w, w_self, dt,
+                     mesh=None, feats_plan=None):
+    """The full-graph forward's ELL aggregations, shared by
+    ``full_graph_forward`` and ``layer0_agg`` so that a hoisted layer-0
+    result is the forward's own.  ``dt`` is the feature table's dtype.
+
+    -> ``(replicate, sage_sum, gcn_sum)``: ``replicate(src)`` casts and
+    constrains a layer's gather source; ``sage_sum(srcr, out_dt)`` is
+    Σ_k mask[n,k]·srcr[idx[n,k]] and ``gcn_sum(srcr, out_dt)`` is
+    Σ_k w[n,k]·srcr[idx[n,k]] + w_self[n]·srcr[n].  The kernel paths
+    return the sum in the aggregation dtype, as the kernel writes it, and
+    the caller casts it to ``out_dt``; the einsum path casts it in the
+    same program, because XLA may keep an einsum's excess precision up
+    to that cast.
+    """
+    from repro import sharding as sh
+
+    maskb = ell_w > 0
+    agg_dt = jnp.bfloat16 if cfg.dtype == "bfloat16" else dt
+    # aggregation consumes the mask in agg_dt: cast the bool ONCE
+    # instead of round-tripping the f32 mask (bool->f32->bf16 was a
+    # second full [n, K] pass per layer under dtype="bfloat16")
+    mask_agg = maskb.astype(agg_dt)
+    fs_active = (feats_plan is not None and cfg.use_agg_kernel
+                 and cfg.model in ("gcn", "graphsage"))
+    tab_axes = (sh.NODES, None) if fs_active else (None, None)
+
+    def replicate(src):
+        """Cast + constrain the per-layer gather source ONCE; every
+        consumer (aggregation, gather, fused self branch) shares the
+        result, so each layer emits a single table constraint.  Under a
+        feats_plan the "replicated" name is historical: the constraint
+        is NODES-row-sharded and no full copy exists anywhere."""
+        return sh.constrain(src.astype(agg_dt), tab_axes)
+
+    def agg_w(srcr, w_edge, out_dt, self_w=None):
+        """Σ_k w_edge[n,k] · srcr[ell_idx[n,k]] (+ the fused
+        self_w[n] · srcr[n] on the kernel paths) without the [n,K,d]
+        blowup; ``srcr`` is the already cast+constrained table."""
+        # fused epilogue: the self row IS the source table row b, so the
+        # kernel consumes the same constrained table twice
+        fused = ({} if self_w is None else
+                 dict(self_rows=srcr, w_self=self_w.astype(agg_dt)))
+        if fs_active:
+            from repro.kernels.neighbor_agg.ops import neighbor_agg_featshard
+            return neighbor_agg_featshard(
+                srcr, w_edge.astype(agg_dt), feats_plan, **fused,
+                b_tile=cfg.agg_b_tile,
+                d_tile=cfg.agg_d_tile,
+                k_slab=cfg.agg_k_slab)
+        if cfg.use_agg_kernel:
+            return _kernel_agg(cfg, srcr, ell_idx, w_edge.astype(agg_dt),
+                               mesh=mesh, **fused)
+        return jnp.einsum("nk,nkd->nd", w_edge.astype(agg_dt),
+                          jnp.take(srcr, ell_idx, axis=0)).astype(out_dt)
+
+    def sage_sum(srcr, out_dt):
+        return agg_w(srcr, mask_agg, out_dt)
+
+    def gcn_sum(srcr, out_dt):
+        if cfg.use_agg_kernel:
+            return agg_w(srcr, ell_w, out_dt, w_self)
+        # the self branch rides the SAME cast table as agg_w (one
+        # constraint per layer, matching the fused kernel's operand
+        # plumbing)
+        return agg_w(srcr, ell_w, out_dt) + (
+            w_self.astype(agg_dt)[:, None] * srcr).astype(out_dt)
+
+    return replicate, sage_sum, gcn_sum
+
+
+def layer0_agg(cfg: GNNConfig, feats, ell_idx, ell_w, w_self):
+    """Layer 0's neighbour aggregation of the fixed feature table,
+    exactly as ``full_graph_forward`` computes it on one device (GCN: the
+    sum with its self term; GraphSAGE: the masked neighbour sum before
+    ``/ cnt``): on the kernel path in the dtype the kernel writes, on the
+    einsum path in the features' dtype.  It does not depend on the
+    parameters, so a full-graph source computes it once per bind and
+    passes it back as the forward's ``agg0``.  -> None where layer 0's
+    aggregation does depend on them: GAT (its ``α``) and a layer 0 that
+    transforms before it gathers (``d_out < d_in``)."""
+    d_in, d_out = layer_dims(cfg, feats.shape[1])[0]
+    if cfg.model not in ("gcn", "graphsage") or d_out < d_in:
+        return None
+    replicate, sage_sum, gcn_sum = _ell_aggregation(
+        cfg, ell_idx, ell_w, w_self, feats.dtype)
+    summed = gcn_sum if cfg.model == "gcn" else sage_sum
+    return summed(replicate(feats), feats.dtype)
+
+
 def full_graph_forward(params, cfg: GNNConfig, feats, ell_idx, ell_w,
                        w_self, mesh=None, feats_plan=None,
-                       return_layers=False):
+                       return_layers=False, agg0=None):
     """feats [n, r]; ell_idx/ell_w [n, K]; w_self [n] -> logits [n, C].
 
     Distributed-execution shape (§Perf H1, measured in EXPERIMENTS.md):
@@ -285,85 +375,37 @@ def full_graph_forward(params, cfg: GNNConfig, feats, ell_idx, ell_w,
     oracle ``core.inference`` validates its layer-wise path against.
     The default path is untouched (the flag only appends to a Python
     list), so the pre-existing golden loss sequences stay bit-for-bit.
-    """
-    from repro import sharding as sh
 
+    ``agg0`` (``layer0_agg``'s result for the same ``feats`` and ELL)
+    takes the place of layer 0's aggregation: the same bits, without the
+    layer's gather.
+    """
     h = feats
     maskb = ell_w > 0
     mask = maskb.astype(h.dtype)
-    agg_dt = jnp.bfloat16 if cfg.dtype == "bfloat16" else h.dtype
-    # aggregation consumes the mask in agg_dt: cast the bool ONCE
-    # instead of round-tripping the f32 mask (bool->f32->bf16 was a
-    # second full [n, K] pass per layer under dtype="bfloat16")
-    mask_agg = mask if agg_dt == h.dtype else maskb.astype(agg_dt)
+    replicate, sage_sum, gcn_sum = _ell_aggregation(
+        cfg, ell_idx, ell_w, w_self, h.dtype, mesh=mesh,
+        feats_plan=feats_plan)
     n_layers = len(params)
-    fs_active = (feats_plan is not None and cfg.use_agg_kernel
-                 and cfg.model in ("gcn", "graphsage"))
-    tab_axes = (sh.NODES, None) if fs_active else (None, None)
-
-    def replicate(src):
-        """Cast + constrain the per-layer gather source ONCE; every
-        consumer (aggregation, gather, fused self branch) shares the
-        result, so each layer emits a single table constraint.  Under a
-        feats_plan the "replicated" name is historical: the constraint
-        is NODES-row-sharded and no full copy exists anywhere."""
-        return sh.constrain(src.astype(agg_dt), tab_axes)
-
-    def agg_w(srcr, w_edge):
-        """Σ_k w_edge[n,k] · srcr[ell_idx[n,k]] without the [n,K,d]
-        blowup; ``srcr`` is the already cast+constrained table."""
-        if fs_active:
-            from repro.kernels.neighbor_agg.ops import neighbor_agg_featshard
-            return neighbor_agg_featshard(
-                srcr, w_edge.astype(agg_dt), feats_plan,
-                b_tile=cfg.agg_b_tile,
-                d_tile=cfg.agg_d_tile,
-                k_slab=cfg.agg_k_slab).astype(h.dtype)
-        if cfg.use_agg_kernel:
-            return _kernel_agg(cfg, srcr, ell_idx,
-                               w_edge.astype(agg_dt),
-                               mesh=mesh).astype(h.dtype)
-        return jnp.einsum("nk,nkd->nd", w_edge.astype(agg_dt),
-                          jnp.take(srcr, ell_idx, axis=0)).astype(h.dtype)
 
     layers = []
     for li, p in enumerate(params):
         last = li == n_layers - 1
+        hoisted = li == 0 and agg0 is not None
         if cfg.model == "gcn":
             w = p["w"]
             pre = w.shape[1] < h.shape[1]
-            src = (h @ w) if pre else h
-            srcr = replicate(src)
-            if cfg.use_agg_kernel:
-                # fused epilogue: the self row IS the source table row b,
-                # so the kernel consumes the same constrained table twice
-                if fs_active:
-                    from repro.kernels.neighbor_agg.ops import \
-                        neighbor_agg_featshard
-                    agg = neighbor_agg_featshard(
-                        srcr, ell_w.astype(agg_dt), feats_plan,
-                        self_rows=srcr, w_self=w_self.astype(agg_dt),
-                        b_tile=cfg.agg_b_tile,
-                        d_tile=cfg.agg_d_tile,
-                        k_slab=cfg.agg_k_slab).astype(h.dtype)
-                else:
-                    agg = _kernel_agg(cfg, srcr, ell_idx,
-                                      ell_w.astype(agg_dt), self_rows=srcr,
-                                      w_self=w_self.astype(agg_dt),
-                                      mesh=mesh).astype(h.dtype)
-            else:
-                # the self branch rides the SAME cast table as agg_w
-                # (one constraint per layer, matching the fused kernel's
-                # operand plumbing)
-                agg = agg_w(srcr, ell_w) + (w_self.astype(agg_dt)[:, None]
-                                            * srcr).astype(h.dtype)
+            agg = agg0 if hoisted else gcn_sum(
+                replicate((h @ w) if pre else h), h.dtype)
+            agg = agg.astype(h.dtype)
             out = agg if pre else agg @ w
         elif cfg.model == "graphsage":
             wn = p["w_neigh"]
             pre = wn.shape[1] < h.shape[1]
-            src = (h @ wn) if pre else h
             cnt = jnp.maximum(mask.sum(-1, keepdims=True), 1.0)
-            mean = agg_w(replicate(src), mask_agg) / cnt
+            s_nb = agg0 if hoisted else sage_sum(
+                replicate((h @ wn) if pre else h), h.dtype)
+            mean = s_nb.astype(h.dtype) / cnt
             out = h @ p["w_self"] + (mean if pre else mean @ wn)
         else:  # gat — transform once per node, gather the projected z
             z = gat_transform(p, h)

@@ -21,7 +21,9 @@ Span names: ``sample``, ``stage`` and its child ``ring_wait``,
 ``queue_wait`` and ``device_put`` (``core/prefetch.py``,
 ``core/engine.py``).  Counters: ``ell_slots`` and ``ell_edges``, the
 ELL entries one aggregation call reads and those that hold an edge
-(``FullGraphSource.bind``); ``attn_cols`` and ``attn_kernel_cols``,
+(``FullGraphSource.bind``); ``hoisted_agg_layers``, 1 where that bind
+computed layer 0's aggregation of the fixed feature table once for every
+step (``gnn.layer0_agg``), else 0; ``attn_cols`` and ``attn_kernel_cols``,
 GAT's real columns summed over one forward's attention-aggregation calls
 and the columns those calls read as the kernel pads each head (the same
 bind); ``device_gather_rows``, the node ids of every staged sampled

@@ -9,10 +9,14 @@ import os
 import subprocess
 import sys
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs.base import GNNConfig
+from repro.core import gnn as G
+from repro.core import tracing
 from repro.core.engine import (Callback, FullGraphSource, SampledSource,
                                ShardedFullGraphSource, Trainer, TrainPlan,
                                _device_ell)
@@ -184,6 +188,63 @@ def test_trainer_close_releases_ell_reference():
     t.run()
     t.close()
     assert t._ell is None and t.source.ell is None
+    assert t.source.agg0 is None
+
+
+# ---------------------------------------------------------------------------
+# Layer 0's aggregation of the fixed feature table, computed at bind
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["einsum", "kernel"])
+@pytest.mark.parametrize("model", ["gcn", "graphsage"])
+def test_hoisted_layer0_agg_step_is_bit_exact(model, kernel):
+    """The step's loss and gradients with the bind-time ``agg0`` are the
+    un-hoisted forward's, bit for bit (bf16 aggregation, as the chip's
+    configurations run it)."""
+    g = _fresh_graph(n=96, seed=25)
+    cfg = _cfg(g, model=model, use_agg_kernel=kernel, dtype="bfloat16",
+               max_degree=4)
+    tracing.clear()
+    src = FullGraphSource().bind(g, cfg, TrainPlan(n_iters=1))
+    assert tracing.snapshot()["counters"]["hoisted_agg_layers"] == 1
+    assert src.agg0.shape == g.feats.shape
+    assert src.agg0.dtype == (jnp.bfloat16 if kernel else jnp.float32)
+    params = G.init_gnn(jax.random.key(3), cfg, g.feats.shape[1])
+    consts = src.loss_consts()
+
+    def plain(p, consts):
+        idx, w, w_self, feats, labels, nodes, _ = consts
+        logits = G.full_graph_forward(p, cfg, feats, idx, w, w_self)
+        return G.gnn_loss(logits[nodes], labels[nodes], cfg.loss,
+                          cfg.n_classes)
+
+    def hoisted(p, consts):
+        return FullGraphSource._loss_impl(p, None, consts, cfg)
+
+    l0, g0 = jax.jit(jax.value_and_grad(plain))(params, consts)
+    l1, g1 = jax.jit(jax.value_and_grad(hoisted))(params, consts)
+    assert np.asarray(l1).tobytes() == np.asarray(l0).tobytes()
+    for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g0)):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+
+@pytest.mark.parametrize("case", ["gat", "transform_first", "sharded",
+                                  "sampled"])
+def test_layer0_agg_not_hoisted(case):
+    """Where layer 0's aggregation depends on the parameters (GAT's
+    attention, a layer 0 that transforms before it gathers), or the
+    source keeps every layer in its step, nothing is hoisted."""
+    g = _fresh_graph(seed=26)
+    kw = {"gat": dict(model="gat", hidden=24, gat_heads=3),
+          "transform_first": dict(hidden=8)}.get(case, {})
+    cfg = _cfg(g, **kw)
+    src = {"sharded": ShardedFullGraphSource,
+           "sampled": SampledSource}.get(case, FullGraphSource)()
+    t = Trainer(g, cfg, TrainPlan(lr=0.3, n_iters=2, seed=0), source=src)
+    assert tracing.snapshot()["counters"].get("hoisted_agg_layers",
+                                              0) == 0
+    assert getattr(src, "agg0", None) is None
+    assert np.isfinite(t.run().history.losses).all()
 
 
 # ---------------------------------------------------------------------------
