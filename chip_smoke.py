@@ -273,7 +273,7 @@ def fullgraph_ref_program(rcfg, plan, n_layers):
             for li, p in enumerate(params):
                 last = li == n_layers - 1
                 h = hs[-1]
-                src = I._pre_source(rcfg, p, h)
+                src = G.layer_source(rcfg, p, h)
                 out = jax.lax.map(
                     lambda r: chunk_fn(last, r)(p, h[r], gathered(src, r)),
                     chunks(tr if last else all_rows))
@@ -287,7 +287,7 @@ def fullgraph_ref_program(rcfg, plan, n_layers):
             """(dp, dh) of a layer from the cotangent of its output
             rows; dh is None unless ``need_dh``."""
             src, pre_vjp = jax.vjp(
-                lambda p, h: I._pre_source(rcfg, p, h), p, h)
+                lambda p, h: G.layer_source(rcfg, p, h), p, h)
             rc = chunks(rows)
             gc = g_out.reshape(rc.shape + g_out.shape[1:])
 

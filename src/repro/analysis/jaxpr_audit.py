@@ -87,12 +87,14 @@ class Variant:
 
 def sweep_variants() -> List[Variant]:
     """Every committed sweep variant: paradigm x {einsum, kernel}, plus
-    the featshard layout (only reachable on fullgraph_sharded x kernel)
-    and one gcn point covering the kernel's fused self-row epilogue."""
+    the featshard layout (only reachable on fullgraph_sharded x kernel),
+    one gcn point covering the kernel's fused self-row epilogue and one
+    gat point covering the per-head attention calls."""
     from repro.core.experiment import PARADIGMS
     vs = [Variant(p, k) for p in PARADIGMS for k in (False, True)]
     vs.append(Variant("fullgraph_sharded", True, featshard=True))
     vs.append(Variant("fullgraph", True, model="gcn"))
+    vs.append(Variant("fullgraph", True, model="gat"))
     return vs
 
 
@@ -419,7 +421,7 @@ def _audit_inference(graph) -> Tuple[List[Finding], List[Dict]]:
         site = f"inference:chunk+{'kernel' if kernel else 'einsum'}"
         import jax.numpy as jnp
         rows = jnp.arange(c, dtype=jnp.int32)
-        src = I._pre_source(scfg, params[0], feats)
+        src = G.layer_source(scfg, params[0], feats)
         closed = jax.make_jaxpr(
             I._chunk_apply, static_argnums=(0, 1, 2))(
                 scfg, False, None, params[0], feats, src, rows,

@@ -78,10 +78,10 @@ import numpy as np
 
 from repro.configs.base import GNNConfig
 from repro.core import faults
+from repro.core import gnn as G
 from repro.core.engine import _static_cfg
 from repro.core.graph import Graph, to_ell
-from repro.core.inference import (InferenceRun, _chunk_apply, _pre_source,
-                                  layerwise_layers)
+from repro.core.inference import InferenceRun, _chunk_apply, layerwise_layers
 
 
 @dataclasses.dataclass(frozen=True)
@@ -516,7 +516,7 @@ class EmbeddingStore:
         chunk-padded to the build's chunk width so the build pass's
         compiled ``_chunk_apply`` instances are reused verbatim."""
         last = li == len(self.params) - 1
-        src = _pre_source(self._scfg, p, h)
+        src = G.layer_source(self._scfg, p, h)
         cs = self.chunk_size
         outs = []
         for c0 in range(0, len(ids), cs):

@@ -162,7 +162,7 @@ def _count_attn(cfg: GNNConfig, feat_dim: int) -> None:
     if cfg.model != "gat":
         return
     from repro.kernels.neighbor_agg.ops import kernel_cols
-    agg_dt = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
+    agg_dt = G.agg_dtype(cfg, jnp.float32)
     for heads, dh in G.gat_head_dims(cfg, feat_dim):
         tracing.count("attn_cols", heads * dh)
         tracing.count("attn_kernel_cols", heads * (
@@ -712,7 +712,7 @@ class ShardedFullGraphSource(FullGraphSource):
                 cache_rows=cfg.feat_cache_rows)
         fsplan = pcache[pkey]
         d = graph.feats.shape[1]
-        item = 2 if cfg.dtype == "bfloat16" else graph.feats.dtype.itemsize
+        item = np.dtype(G.agg_dtype(cfg, graph.feats.dtype)).itemsize
         st = dict(fsplan.stats)
         st["feat_table_bytes_per_device"] = \
             fsplan.table_bytes_per_device(d, item)

@@ -1,9 +1,11 @@
 """GCN / GraphSAGE(mean) / GAT — the paper's three models (§5), each with
 a full-graph (ELL) and a mini-batch (fan-out tree) forward path sharing
-the same parameters.
+the same parameters and one layer function, ``gnn_layer``, over an
+aggregator per data layout.
 """
 from __future__ import annotations
 
+import dataclasses
 import math
 from typing import Any, Dict, List, Sequence
 
@@ -65,6 +67,12 @@ def init_gnn(key, cfg: GNNConfig, feat_dim: int) -> List[Dict[str, Any]]:
 # layer primitives (shared by both paths)
 # ---------------------------------------------------------------------------
 
+def agg_dtype(cfg: GNNConfig, dt):
+    """The dtype a layer's aggregation gathers and sums in: bfloat16
+    under ``cfg.dtype == "bfloat16"``, else the table's own ``dt``."""
+    return jnp.bfloat16 if cfg.dtype == "bfloat16" else dt
+
+
 def _kernel_agg(cfg: GNNConfig, table, idx, w, self_rows=None,
                 w_self=None, mesh=None):
     """Σ_k w[b,k] · table[idx[b,k]] (+ fused w_self[b] · self_rows[b]
@@ -121,17 +129,6 @@ def _wsum(cfg: GNNConfig, w_edge, h_nb, h_self=None, w_self=None,
                       self_rows=h_self.reshape(b, d) if fused else None,
                       w_self=w_self.reshape(b) if fused else None)
     return out.reshape(lead + (d,))
-
-
-def _gcn_layer(cfg, p, h_self, h_nb, w_edge, w_self, mesh=None):
-    """h_self [..., d]; h_nb [..., K, d]; w_edge [..., K]; w_self [...]."""
-    return _wsum(cfg, w_edge, h_nb, h_self, w_self, mesh=mesh) @ p["w"]
-
-
-def _sage_layer(cfg, p, h_self, h_nb, mask, mesh=None):
-    cnt = jnp.maximum(mask.sum(-1, keepdims=True), 1.0)
-    mean = _wsum(cfg, mask, h_nb, mesh=mesh) / cnt
-    return h_self @ p["w_self"] + mean @ p["w_neigh"]
 
 
 def _gat_attention(s_t, s_nb, s_self, mask):
@@ -224,92 +221,185 @@ def gat_ell_layer(cfg: GNNConfig, p, z, table, idx, mask, last: bool,
     return sum(outs) / heads if last else jnp.concatenate(outs, axis=1)
 
 
-def _apply_layer(cfg: GNNConfig, p, h_self, h_nb, mask, w_edge, w_self,
-                 last: bool, mesh=None):
+# ---------------------------------------------------------------------------
+# one layer for every path, over two aggregators
+# ---------------------------------------------------------------------------
+
+def layer_source(cfg: GNNConfig, p, h):
+    """What a layer gathers from, for the whole input table ``h``:
+    GAT's per-node transform ``z``; ``h @ W`` where the layer narrows
+    (``d_out < d_in``: Ã(hW) == (Ãh)W, and likewise for the GraphSAGE
+    neighbour branch, so the gather moves ``d_out``-wide rows); else
+    ``h`` itself."""
+    if cfg.model == "gat":
+        return gat_transform(p, h)
+    w = p["w"] if cfg.model == "gcn" else p["w_neigh"]
+    return h @ w if w.shape[1] < h.shape[1] else h
+
+
+def gnn_layer(cfg: GNNConfig, p, h_self, src, agg, last: bool):
+    """One layer of the configured model, on every path: the only branch
+    on ``cfg.model`` in the forward maths.
+
+    ``h_self`` is the layer's input at its output rows.  ``src`` is what
+    it gathers from: ``layer_source``'s table, a fan-out level's
+    neighbour rows, or None for ``layer_source(cfg, p, h_self)`` (then
+    ``h_self`` is the whole table).  A source narrower than the layer's
+    input was transformed already, so the layer skips its ``W``.  ``agg``
+    hides the data layout: ``EllAggregator`` (ELL rows) or ``_TreeAgg``
+    (one fan-out level).  ReLU on all layers but the last."""
+    dt = h_self.dtype
+
+    def source():
+        return layer_source(cfg, p, h_self) if src is None else src
+
     if cfg.model == "gcn":
-        out = _gcn_layer(cfg, p, h_self, h_nb, w_edge, w_self, mesh=mesh)
+        s = source()
+        w = p["w"]
+        a = agg.gcn(s, dt)
+        out = a if s.shape[-1] < w.shape[0] else a @ w
     elif cfg.model == "graphsage":
-        out = _sage_layer(cfg, p, h_self, h_nb, mask, mesh=mesh)
+        wn = p["w_neigh"]
+        # count before the source's transform: the jaxpr audit's step
+        # hashes pin this order of equations
+        cnt = jnp.maximum(agg.mask.sum(-1, keepdims=True), 1.0)
+        s = source()
+        mean = agg.sage(s, dt) / cnt
+        out = h_self @ p["w_self"] + (
+            mean if s.shape[-1] < wn.shape[0] else mean @ wn)
     else:
-        out = _gat_layer(p, h_self, h_nb, mask)
-        if last:  # average heads into class logits
-            out = out.reshape(out.shape[:-1] + (cfg.gat_heads, -1)).mean(-2)
+        out = agg.gat(p, source(), last)
     return out if last else jax.nn.relu(out)
 
 
-# ---------------------------------------------------------------------------
-# full-graph forward (ELL)
-# ---------------------------------------------------------------------------
+@dataclasses.dataclass(frozen=True)
+class _TreeAgg:
+    """``gnn_layer``'s aggregation over one fan-out level: the targets'
+    rows ``h_self`` [..., d], the edges' ``mask`` (in ``h_self``'s dtype)
+    and weights ``w_edge`` [..., K], the self-loop weights ``w_self``
+    [...]; the source is the next level's rows [..., K, d], never
+    transformed first.  GAT applies ``W`` per slot (``_gat_layer``),
+    since every slot holds a row of its own."""
+    cfg: GNNConfig
+    h_self: Any
+    mask: Any
+    w_edge: Any
+    w_self: Any
+    mesh: Any = None
 
-def _ell_aggregation(cfg: GNNConfig, ell_idx, ell_w, w_self, dt,
-                     mesh=None, feats_plan=None):
-    """The full-graph forward's ELL aggregations, shared by
-    ``full_graph_forward`` and ``layer0_agg`` so that a hoisted layer-0
-    result is the forward's own.  ``dt`` is the feature table's dtype.
+    def gcn(self, h_nb, dt):
+        return _wsum(self.cfg, self.w_edge, h_nb, self.h_self, self.w_self,
+                     mesh=self.mesh)
 
-    -> ``(replicate, sage_sum, gcn_sum)``: ``replicate(src)`` casts and
-    constrains a layer's gather source; ``sage_sum(srcr, out_dt)`` is
-    Σ_k mask[n,k]·srcr[idx[n,k]] and ``gcn_sum(srcr, out_dt)`` is
-    Σ_k w[n,k]·srcr[idx[n,k]] + w_self[n]·srcr[n].  The kernel paths
-    return the sum in the aggregation dtype, as the kernel writes it, and
-    the caller casts it to ``out_dt``; the einsum path casts it in the
-    same program, because XLA may keep an einsum's excess precision up
-    to that cast.
-    """
-    from repro import sharding as sh
+    def sage(self, h_nb, dt):
+        return _wsum(self.cfg, self.mask, h_nb, mesh=self.mesh)
 
-    maskb = ell_w > 0
-    agg_dt = jnp.bfloat16 if cfg.dtype == "bfloat16" else dt
-    # aggregation consumes the mask in agg_dt: cast the bool ONCE
-    # instead of round-tripping the f32 mask (bool->f32->bf16 was a
-    # second full [n, K] pass per layer under dtype="bfloat16")
-    mask_agg = maskb.astype(agg_dt)
-    fs_active = (feats_plan is not None and cfg.use_agg_kernel
-                 and cfg.model in ("gcn", "graphsage"))
-    tab_axes = (sh.NODES, None) if fs_active else (None, None)
+    def gat(self, p, h_nb, last):
+        out = _gat_layer(p, self.h_self, h_nb, self.mask)
+        if last:  # average heads into class logits
+            out = out.reshape(out.shape[:-1] + (self.cfg.gat_heads, -1)
+                              ).mean(-2)
+        return out
 
-    def replicate(src):
-        """Cast + constrain the per-layer gather source ONCE; every
-        consumer (aggregation, gather, fused self branch) shares the
-        result, so each layer emits a single table constraint.  Under a
-        feats_plan the "replicated" name is historical: the constraint
-        is NODES-row-sharded and no full copy exists anywhere."""
-        return sh.constrain(src.astype(agg_dt), tab_axes)
 
-    def agg_w(srcr, w_edge, out_dt, self_w=None):
-        """Σ_k w_edge[n,k] · srcr[ell_idx[n,k]] (+ the fused
-        self_w[n] · srcr[n] on the kernel paths) without the [n,K,d]
-        blowup; ``srcr`` is the already cast+constrained table."""
-        # fused epilogue: the self row IS the source table row b, so the
+class EllAggregator:
+    """``gnn_layer``'s aggregation over ELL rows, shared by
+    ``full_graph_forward``, ``layer0_agg`` and the layer-wise inference
+    passes, so that a hoisted layer-0 result and an inference chunk are
+    the forward's own.  ``dt`` is the feature table's dtype.
+
+    ``idx``/``w`` ``[r, K]`` and ``w_self`` ``[r]`` are the ELL rows of
+    the output rows: ``rows`` (their global ids, for a chunk) or, where
+    None, all n.  The kernel paths return a sum in the aggregation dtype,
+    as the kernel writes it, and ``gcn``/``sage`` cast it to the layer's
+    dtype; the einsum path casts it in the same program, because XLA may
+    keep an einsum's excess precision up to that cast.  ``agg0``
+    (``layer0_agg``'s result) stands in for the first ``gcn``/``sage``
+    sum; the forward drops it after layer 0.  ``feats_plan``
+    (gcn/graphsage on the kernel path, all rows) runs the sums through
+    ``neighbor_agg_featshard`` on a NODES-row-sharded table."""
+
+    def __init__(self, cfg: GNNConfig, idx, w, w_self, dt, mesh=None,
+                 feats_plan=None, rows=None, agg0=None):
+        from repro import sharding as sh
+        self.cfg, self.idx, self.w, self.w_self = cfg, idx, w, w_self
+        self.mesh, self.rows, self.agg0 = mesh, rows, agg0
+        self.maskb = w > 0                      # GAT's real slots
+        self.mask = self.maskb.astype(dt)       # GraphSAGE's count
+        self.agg_dt = agg_dtype(cfg, dt)
+        # the sums read the mask in agg_dt, cast from a bool (not through
+        # the f32 mask: a second [n, K] pass under bfloat16) of its own
+        # compare, an equation the jaxpr audit's step hashes pin
+        self.mask_agg = (w > 0).astype(self.agg_dt)
+        fs_active = (feats_plan is not None and cfg.use_agg_kernel
+                     and cfg.model in ("gcn", "graphsage"))
+        self.fs_plan = feats_plan if fs_active else None
+        self.tab_axes = (sh.NODES, None) if fs_active else (None, None)
+
+    def replicate(self, src):
+        """Cast + constrain a layer's gather source ONCE; every consumer
+        (aggregation, gather, fused self branch) shares the result, so
+        each layer emits a single table constraint.  Under a feats plan
+        the "replicated" name is historical: the constraint is
+        NODES-row-sharded and no full copy exists anywhere."""
+        from repro import sharding as sh
+        return sh.constrain(src.astype(self.agg_dt), self.tab_axes)
+
+    def _self_rows(self, srcr):
+        return (srcr if self.rows is None
+                else jnp.take(srcr, self.rows, axis=0))
+
+    def _sum(self, srcr, w_edge, out_dt, self_w=None):
+        """Σ_k w_edge[n,k] · srcr[idx[n,k]] (+ the fused self_w[n] ·
+        srcr[n] on the kernel paths) without the [n,K,d] blowup;
+        ``srcr`` is the already cast+constrained table."""
+        cfg, agg_dt = self.cfg, self.agg_dt
+        # fused epilogue: the self row IS the source table's row, so the
         # kernel consumes the same constrained table twice
         fused = ({} if self_w is None else
-                 dict(self_rows=srcr, w_self=self_w.astype(agg_dt)))
-        if fs_active:
+                 dict(self_rows=self._self_rows(srcr),
+                      w_self=self_w.astype(agg_dt)))
+        if self.fs_plan is not None:
             from repro.kernels.neighbor_agg.ops import neighbor_agg_featshard
             return neighbor_agg_featshard(
-                srcr, w_edge.astype(agg_dt), feats_plan, **fused,
+                srcr, w_edge.astype(agg_dt), self.fs_plan, **fused,
                 b_tile=cfg.agg_b_tile,
                 d_tile=cfg.agg_d_tile,
                 k_slab=cfg.agg_k_slab)
         if cfg.use_agg_kernel:
-            return _kernel_agg(cfg, srcr, ell_idx, w_edge.astype(agg_dt),
-                               mesh=mesh, **fused)
+            return _kernel_agg(cfg, srcr, self.idx, w_edge.astype(agg_dt),
+                               mesh=self.mesh, **fused)
         return jnp.einsum("nk,nkd->nd", w_edge.astype(agg_dt),
-                          jnp.take(srcr, ell_idx, axis=0)).astype(out_dt)
+                          jnp.take(srcr, self.idx, axis=0)).astype(out_dt)
 
-    def sage_sum(srcr, out_dt):
-        return agg_w(srcr, mask_agg, out_dt)
+    def sage_sum(self, srcr, out_dt):
+        """Σ_k mask[n,k]·srcr[idx[n,k]]."""
+        return self._sum(srcr, self.mask_agg, out_dt)
 
-    def gcn_sum(srcr, out_dt):
-        if cfg.use_agg_kernel:
-            return agg_w(srcr, ell_w, out_dt, w_self)
-        # the self branch rides the SAME cast table as agg_w (one
-        # constraint per layer, matching the fused kernel's operand
-        # plumbing)
-        return agg_w(srcr, ell_w, out_dt) + (
-            w_self.astype(agg_dt)[:, None] * srcr).astype(out_dt)
+    def gcn_sum(self, srcr, out_dt):
+        """Σ_k w[n,k]·srcr[idx[n,k]] + w_self[n]·srcr[n]."""
+        if self.cfg.use_agg_kernel:
+            return self._sum(srcr, self.w, out_dt, self.w_self)
+        # the self branch rides the SAME cast table as the sum (one
+        # constraint per layer, matching the fused kernel's operands)
+        return self._sum(srcr, self.w, out_dt) + (
+            self.w_self.astype(self.agg_dt)[:, None]
+            * self._self_rows(srcr)).astype(out_dt)
 
-    return replicate, sage_sum, gcn_sum
+    def gcn(self, src, dt):
+        s = (self.gcn_sum(self.replicate(src), dt) if self.agg0 is None
+             else self.agg0)
+        return s.astype(dt)
+
+    def sage(self, src, dt):
+        s = (self.sage_sum(self.replicate(src), dt) if self.agg0 is None
+             else self.agg0)
+        return s.astype(dt)
+
+    def gat(self, p, z, last):
+        return gat_ell_layer(self.cfg, p, z, self.replicate(z), self.idx,
+                             self.maskb, last, rows=self.rows,
+                             mesh=self.mesh)
 
 
 def layer0_agg(cfg: GNNConfig, feats, ell_idx, ell_w, w_self):
@@ -325,25 +415,28 @@ def layer0_agg(cfg: GNNConfig, feats, ell_idx, ell_w, w_self):
     d_in, d_out = layer_dims(cfg, feats.shape[1])[0]
     if cfg.model not in ("gcn", "graphsage") or d_out < d_in:
         return None
-    replicate, sage_sum, gcn_sum = _ell_aggregation(
-        cfg, ell_idx, ell_w, w_self, feats.dtype)
-    summed = gcn_sum if cfg.model == "gcn" else sage_sum
-    return summed(replicate(feats), feats.dtype)
+    agg = EllAggregator(cfg, ell_idx, ell_w, w_self, feats.dtype)
+    summed = agg.gcn_sum if cfg.model == "gcn" else agg.sage_sum
+    return summed(agg.replicate(feats), feats.dtype)
 
+
+# ---------------------------------------------------------------------------
+# full-graph forward (ELL)
+# ---------------------------------------------------------------------------
 
 def full_graph_forward(params, cfg: GNNConfig, feats, ell_idx, ell_w,
                        w_self, mesh=None, feats_plan=None,
                        return_layers=False, agg0=None):
-    """feats [n, r]; ell_idx/ell_w [n, K]; w_self [n] -> logits [n, C].
+    """feats [n, r]; ell_idx/ell_w [n, K]; w_self [n] -> logits [n, C]:
+    ``gnn_layer`` per layer over the ELL aggregator.
 
     Distributed-execution shape (§Perf H1, measured in EXPERIMENTS.md):
       * the gather SOURCE is explicitly replicated across the mesh before
         jnp.take — one all-gather of [n, d] instead of GSPMD's
         all-reduce of the [n, K, d] gather output (K x the wire bytes);
       * when a layer shrinks its width (d_out < d_in), the linear
-        transform runs BEFORE aggregation (Ã(hW) == (Ãh)W for GCN and
-        the GraphSAGE neighbor branch) so the gather moves d_out-wide
-        rows;
+        transform runs BEFORE aggregation (``layer_source``) so the
+        gather moves d_out-wide rows;
       * aggregation traffic runs in cfg.dtype (bf16 at production scale).
     All three are exact (up to float associativity).
 
@@ -381,37 +474,12 @@ def full_graph_forward(params, cfg: GNNConfig, feats, ell_idx, ell_w,
     layer's gather.
     """
     h = feats
-    maskb = ell_w > 0
-    mask = maskb.astype(h.dtype)
-    replicate, sage_sum, gcn_sum = _ell_aggregation(
-        cfg, ell_idx, ell_w, w_self, h.dtype, mesh=mesh,
-        feats_plan=feats_plan)
-    n_layers = len(params)
-
+    agg = EllAggregator(cfg, ell_idx, ell_w, w_self, h.dtype, mesh=mesh,
+                        feats_plan=feats_plan, agg0=agg0)
     layers = []
     for li, p in enumerate(params):
-        last = li == n_layers - 1
-        hoisted = li == 0 and agg0 is not None
-        if cfg.model == "gcn":
-            w = p["w"]
-            pre = w.shape[1] < h.shape[1]
-            agg = agg0 if hoisted else gcn_sum(
-                replicate((h @ w) if pre else h), h.dtype)
-            agg = agg.astype(h.dtype)
-            out = agg if pre else agg @ w
-        elif cfg.model == "graphsage":
-            wn = p["w_neigh"]
-            pre = wn.shape[1] < h.shape[1]
-            cnt = jnp.maximum(mask.sum(-1, keepdims=True), 1.0)
-            s_nb = agg0 if hoisted else sage_sum(
-                replicate((h @ wn) if pre else h), h.dtype)
-            mean = s_nb.astype(h.dtype) / cnt
-            out = h @ p["w_self"] + (mean if pre else mean @ wn)
-        else:  # gat — transform once per node, gather the projected z
-            z = gat_transform(p, h)
-            out = gat_ell_layer(cfg, p, z, replicate(z), ell_idx, maskb,
-                                last, mesh=mesh)
-        h = out if last else jax.nn.relu(out)
+        h = gnn_layer(cfg, p, h, None, agg, li == len(params) - 1)
+        agg.agg0 = None                          # layer 0's only
         if return_layers:
             layers.append(h)
     return (h, layers) if return_layers else h
@@ -429,16 +497,12 @@ def minibatch_forward(params, cfg: GNNConfig, hop_feats: Sequence,
     (sharded sources) runs the kernel path shard-locally over the
     NODES-sharded target axis; the einsum path ignores it."""
     hs = list(hop_feats)
-    n_layers = len(params)
     for li, p in enumerate(params):
-        last = li == n_layers - 1
-        new_hs = []
-        for d in range(len(hs) - 1):
-            new_hs.append(_apply_layer(
-                cfg, p, hs[d], hs[d + 1],
-                masks[d].astype(hs[d].dtype), weights[d], self_w[d], last,
-                mesh=mesh))
-        hs = new_hs
+        last = li == len(params) - 1
+        hs = [gnn_layer(cfg, p, hs[d], hs[d + 1],
+                        _TreeAgg(cfg, hs[d], masks[d].astype(hs[d].dtype),
+                                 weights[d], self_w[d], mesh), last)
+              for d in range(len(hs) - 1)]
     assert len(hs) == 1
     return hs[0]                                      # [b, C]
 

@@ -9,8 +9,9 @@ layer-(l+1) work, so a k-layer model over n nodes costs O(k · n) ELL
 gathers total and every query afterwards is a table lookup.
 
 The node axis is CHUNKED: each layer streams [chunk_size]-row slices of
-the host ELL through the existing aggregation paths —
-``cfg.use_agg_kernel`` routes a chunk through the batch-tiled Pallas
+the host ELL through the forward's own layer, ``gnn.gnn_layer`` over
+``gnn.EllAggregator`` at the chunk's rows — ``cfg.use_agg_kernel``
+routes a chunk through the batch-tiled Pallas
 kernel (shard-locally over a NODES mesh when ``mesh`` is given, the PR-5
 sharded path), otherwise the einsum gather.  Chunk staging reuses the
 engine's ``Prefetcher`` + ``HostStagingRing``: a background thread
@@ -18,9 +19,11 @@ copies the next chunk's ELL rows into recycled staging buffers while
 the device computes the current one.
 
 Equivalence contract (test-enforced, tests/test_inference.py):
-- per-layer ``allclose`` with the naive ``full_graph_forward`` for every
-  model and both aggregation paths, at any chunk size (including ones
-  that do not divide n);
+- a chunk runs ``full_graph_forward``'s own layer restricted to its
+  rows, so the per-layer tables are ``allclose`` to the naive forward's
+  for every model and both aggregation paths, at any chunk size
+  (including ones that do not divide n), and bit-equal to the compiled
+  forward's under bfloat16;
 - on a 1-device mesh the kernel path is BIT-identical to the unsharded
   kernel path (inherited from ``neighbor_agg_sharded``);
 - ``prefetch`` on/off is bit-identical (same chunks, same ops).
@@ -52,121 +55,27 @@ from repro.core.prefetch import HostStagingRing, Prefetcher
 # Compiled per-chunk layer step
 # ---------------------------------------------------------------------------
 
-@jax.jit
-def _matmul(h, wmat):
-    return h @ wmat
-
-
-_gat_transform = jax.jit(G.gat_transform)
-
-
-def _pre_source(cfg: GNNConfig, p, h):
-    """The full forward's width-shrinking trick, once per LAYER (not per
-    chunk): when a layer narrows (d_out < d_in) the linear transform
-    runs before aggregation (Ã(hW) == (Ãh)W), so every chunk gathers
-    d_out-wide rows.  GAT's source is its per-node transform ``z``
-    ``[n, H·dh]``, which every chunk gathers."""
-    if cfg.model == "gat":
-        return _gat_transform(p, h)
-    wmat = p.get("w") if cfg.model == "gcn" else p.get("w_neigh")
-    if wmat is not None and wmat.shape[1] < h.shape[1]:
-        return _matmul(h, wmat)
-    return h
-
-
 @functools.partial(jax.jit, static_argnums=(0, 1, 2))
 def _chunk_apply(cfg: GNNConfig, last: bool, mesh, p, h, src, rows, idx,
-                 w, w_self):
-    """One node-chunk of one layer, mirroring ``full_graph_forward``'s
-    per-layer body row-sliced to the chunk.
+                 w, w_self, feats_plan=None):
+    """One node-chunk of one layer: ``gnn.gnn_layer`` with the forward's
+    own ELL aggregator, restricted to the chunk's output rows.
 
-    ``h`` [n, d_in] is the full previous-layer table, ``src`` the
-    (possibly pre-transformed) gather source table; ``rows`` [c] are the
-    chunk's global node ids, ``idx``/``w`` [c, K] its ELL rows and
-    ``w_self`` [c] the self-loop weights.  Padded tail rows carry zero
-    weights (their aggregation is exactly zero) and are trimmed by the
-    caller.  Jitted once per (normalized cfg, last, mesh, shapes) at
-    module level, so the store's incremental re-embeds reuse the build
-    pass's compiled functions.
+    ``h`` [n, d_in] is the full previous-layer table and ``src`` the
+    layer's gather source (``gnn.layer_source`` of ``h``); ``rows`` [c]
+    are the chunk's global node ids, ``idx``/``w`` [c, K] its ELL rows
+    and ``w_self`` [c] the self-loop weights.  Padded tail rows carry
+    zero weights (their aggregation is exactly zero) and are trimmed by
+    the caller.  ``rows=None`` is one chunk of every row, with ``src``
+    None (the layer transforms ``h`` itself), as the featshard pass runs
+    it under its ``feats_plan``.  Jitted once per (normalized cfg, last,
+    mesh, shapes) at module level, so the store's incremental re-embeds
+    reuse the build pass's compiled functions.
     """
-    agg_dt = jnp.bfloat16 if cfg.dtype == "bfloat16" else h.dtype
-    maskb = w > 0
-    mask = maskb.astype(h.dtype)
-    # cast the bool mask straight to agg_dt where aggregation consumes
-    # it — bool->f32->bf16 was a second full [c, K] pass under bf16
-    mask_agg = mask if agg_dt == h.dtype else maskb.astype(agg_dt)
-
-    def agg_w(table, w_edge):
-        t = table.astype(agg_dt)
-        if cfg.use_agg_kernel:
-            return G._kernel_agg(cfg, t, idx, w_edge.astype(agg_dt),
-                                 mesh=mesh).astype(h.dtype)
-        return jnp.einsum("ck,ckd->cd", w_edge.astype(agg_dt),
-                          jnp.take(t, idx, axis=0)).astype(h.dtype)
-
-    if cfg.model == "gcn":
-        wmat = p["w"]
-        pre = wmat.shape[1] < h.shape[1]
-        if cfg.use_agg_kernel:
-            # fused epilogue: the chunk's self rows come from the same
-            # cast source table the kernel gathers from
-            srcr = src.astype(agg_dt)
-            agg = G._kernel_agg(cfg, srcr, idx, w.astype(agg_dt),
-                                self_rows=jnp.take(srcr, rows, axis=0),
-                                w_self=w_self.astype(agg_dt),
-                                mesh=mesh).astype(h.dtype)
-        else:
-            agg = agg_w(src, w) \
-                + w_self[:, None] * jnp.take(src, rows, axis=0)
-        out = agg if pre else agg @ wmat
-    elif cfg.model == "graphsage":
-        wn = p["w_neigh"]
-        pre = wn.shape[1] < h.shape[1]
-        cnt = jnp.maximum(mask.sum(-1, keepdims=True), 1.0)
-        mean = agg_w(src, mask_agg) / cnt
-        out = jnp.take(h, rows, axis=0) @ p["w_self"] \
-            + (mean if pre else mean @ wn)
-    else:  # gat — src is z, the layer's transform of every node
-        out = G.gat_ell_layer(cfg, p, src, src.astype(agg_dt), idx, maskb,
-                              last, rows=rows, mesh=mesh)
-    return out if last else jax.nn.relu(out)
-
-
-@functools.partial(jax.jit, static_argnums=(0, 1))
-def _featshard_layer(cfg: GNNConfig, last: bool, fsplan, p, h, w, w_self):
-    """One FULL layer over the NODES-sharded table (feats_layout =
-    "sharded"): no chunk loop and no replicated source anywhere — the
-    whole [n_pad, d] table stays row-sharded, layer l's output feeds
-    layer l+1 in place (the ISSUE's "layer tables stay NODES-sharded"
-    serving requirement).  Mirrors ``full_graph_forward``'s gcn /
-    graphsage bodies through ``neighbor_agg_featshard``; ``fsplan`` is
-    the plan for THIS ell/mesh (a pytree argument)."""
-    from repro.kernels.neighbor_agg.ops import neighbor_agg_featshard
-    agg_dt = jnp.bfloat16 if cfg.dtype == "bfloat16" else h.dtype
-    kw = dict(b_tile=cfg.agg_b_tile,
-              d_tile=cfg.agg_d_tile, k_slab=cfg.agg_k_slab)
-    if cfg.model == "gcn":
-        wmat = p["w"]
-        pre = wmat.shape[1] < h.shape[1]
-        srcr = ((h @ wmat) if pre else h).astype(agg_dt)
-        agg = neighbor_agg_featshard(
-            srcr, w.astype(agg_dt), fsplan, self_rows=srcr,
-            w_self=w_self.astype(agg_dt), **kw).astype(h.dtype)
-        out = agg if pre else agg @ wmat
-    else:  # graphsage
-        wn = p["w_neigh"]
-        pre = wn.shape[1] < h.shape[1]
-        src = (h @ wn) if pre else h
-        maskb = w > 0
-        mask = maskb.astype(h.dtype)
-        cnt = jnp.maximum(mask.sum(-1, keepdims=True), 1.0)
-        # bool -> agg_dt directly (not via the f32 mask): one cast pass
-        mask_agg = mask if agg_dt == h.dtype else maskb.astype(agg_dt)
-        mean = neighbor_agg_featshard(
-            src.astype(agg_dt), mask_agg, fsplan,
-            **kw).astype(h.dtype) / cnt
-        out = h @ p["w_self"] + (mean if pre else mean @ wn)
-    return out if last else jax.nn.relu(out)
+    agg = G.EllAggregator(cfg, idx, w, w_self, h.dtype, mesh=mesh,
+                          feats_plan=feats_plan, rows=rows)
+    h_self = h if rows is None else jnp.take(h, rows, axis=0)
+    return G.gnn_layer(cfg, p, h_self, src, agg, last)
 
 
 # ---------------------------------------------------------------------------
@@ -317,7 +226,8 @@ def _featshard_run(params, scfg: GNNConfig, feats, ell,
     for li, p in enumerate(params):
         lt0 = time.perf_counter()
         last = li == len(params) - 1
-        h = _featshard_layer(scfg, last, fsplan, p, h, w_d, ws_d)
+        h = _chunk_apply(scfg, last, None, p, h, src=None, rows=None,
+                         idx=None, w=w_d, w_self=ws_d, feats_plan=fsplan)
         jax.block_until_ready(h)
         # h itself stays padded + NODES-sharded for the next layer; the
         # returned table is trimmed to the real rows
@@ -326,7 +236,7 @@ def _featshard_run(params, scfg: GNNConfig, feats, ell,
         faults.maybe_crash("infer.after_layer")
     total = time.perf_counter() - t0
     d = feats.shape[1]
-    item = 2 if scfg.dtype == "bfloat16" else np.dtype(feats.dtype).itemsize
+    item = np.dtype(G.agg_dtype(scfg, feats.dtype)).itemsize
     stats = {
         "n_nodes": n, "n_layers": len(params), "chunk_size": n,
         "n_chunks": 1, "chunk_steps": len(params),
@@ -375,7 +285,7 @@ def layerwise_layers(params, cfg: GNNConfig, feats,
         for li, p in enumerate(params):
             lt0 = time.perf_counter()
             last = li == len(params) - 1
-            src = _pre_source(scfg, p, h)
+            src = G.layer_source(scfg, p, h)
             outs = []
             for _ in range(stream.n_chunks):
                 (rows, cidx, cw, cws), m, slot = stream.next()

@@ -117,10 +117,11 @@ def test_prefetch_off_bit_identical(small_graph):
         assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
-@pytest.mark.parametrize("model", ["gcn", "graphsage"])
+@pytest.mark.parametrize("model", ["gcn", "graphsage", "gat"])
 def test_one_device_mesh_bit_equal(small_graph, model):
     """Sharded kernel path on a 1-device mesh == unsharded kernel path,
-    bit for bit, per layer (the PR 5 contract carried into inference)."""
+    bit for bit, per layer (the sharded kernel's contract carried into
+    inference); for GAT, through each head's kernel call."""
     cfg = _cfg(small_graph, model=model, use_agg_kernel=True)
     params = G.init_gnn(jax.random.key(4), cfg,
                         small_graph.feats.shape[1])
@@ -129,6 +130,28 @@ def test_one_device_mesh_bit_equal(small_graph, model):
                                 mesh=sh.node_mesh(1))
     for a, b in zip(base.layers, shrd.layers):
         assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("model", ["gcn", "graphsage", "gat"])
+@pytest.mark.parametrize("kernel", [False, True])
+def test_layerwise_bf16_bit_equal_to_forward(small_graph, model, kernel):
+    """Under dtype="bfloat16" every chunk runs the compiled forward's own
+    layer on its rows, so each layer table equals the forward's bit for
+    bit.  A chunk path with its own maths (say, a GCN self term summed in
+    the table's dtype, not the aggregation dtype) drifts by bf16 ulps."""
+    cfg = _cfg(small_graph, model=model, use_agg_kernel=kernel,
+               dtype="bfloat16")
+    params = G.init_gnn(jax.random.key(5), cfg,
+                        small_graph.feats.shape[1])
+    idx, w, ws = to_ell(small_graph)
+    forward = jax.jit(G.full_graph_forward, static_argnums=1,
+                      static_argnames="return_layers")
+    _, want = forward(params, cfg, jnp.asarray(small_graph.feats),
+                      jnp.asarray(idx), jnp.asarray(w), jnp.asarray(ws),
+                      return_layers=True)
+    run = layerwise_embeddings(params, cfg, small_graph, chunk_size=37)
+    for li, (a, b) in enumerate(zip(run.layers, want)):
+        assert np.array_equal(np.asarray(a), np.asarray(b)), f"layer {li}"
 
 
 def test_empty_graph_rejected(small_graph):
